@@ -25,6 +25,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (cv_mema, device_compare, device_ring, fault_injection,
                fig04_permutation, fig05_comm_volume, fig06_block_fetch,
                fig07_config_sweep, fig08_breakdown, fig09_strong_scaling,
@@ -92,6 +94,7 @@ def main(argv=None) -> int:
                     metavar="PATH",
                     help=f"also write rows as JSON (default {DEFAULT_JSON})")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     modules = [m for m in MODULES
                if args.only is None or args.only in m.__name__]

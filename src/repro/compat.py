@@ -1,21 +1,19 @@
-"""JAX version-compatibility shims — the single point of API-drift repair.
+"""JAX API shims — the single point where drifting JAX names are spelled.
 
-Supported JAX: **0.4.37** (the CPU wheel baked into the build image; see
+Supported JAX: **0.9.0** (jax, jaxlib; libtpu 0.0.34 on the chip — see
 ``requirements.txt``). JAX renames and relocates public APIs between minor
-releases — ``shard_map`` moved from ``jax.experimental.shard_map`` to
-``jax.shard_map``, Pallas-TPU renamed ``TPUCompilerParams`` to
-``CompilerParams`` — and a codebase that spells the new (or old) name at
-every call site breaks wholesale on every such move.
+releases (``shard_map`` moved to ``jax.shard_map`` and its ``check_rep``
+knob became ``check_vma``; Pallas-TPU renamed ``TPUCompilerParams`` to
+``CompilerParams``), and a codebase that spells such a name at every call
+site breaks wholesale on every move.
 
-Policy: resolve each drifting symbol **once, here**, trying the newest
-location first and falling back to the older one. Everything else in the
-repo imports from ``repro.compat`` and never references the ``jax.*``
-spelling directly (enforced by grep in review; exercised by
-``tests/test_import_sweep.py``, which imports every ``repro.*`` module so
-the next rename fails loudly at collection time instead of deep inside a
-subprocess assertion). When you hit the next rename: add a resolver below
-with the same try-new/fallback-old shape, migrate call sites, and note the
-supported-version change in ROADMAP.md "Open items".
+Policy: resolve each drifting symbol **once, here**, for the one installed
+JAX. Everything else in the repo imports from ``repro.compat`` and never
+references the ``jax.*`` spelling directly (replint RS002;
+``tests/test_import_sweep.py`` imports every ``repro.*`` module so the
+next rename fails loudly at collection time instead of deep inside a
+subprocess assertion). When the pin moves: repair the resolver below,
+keep call sites unchanged, and note the change in ROADMAP.md.
 """
 
 from __future__ import annotations
@@ -27,51 +25,49 @@ import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 
-__all__ = ["shard_map", "tpu_compiler_params", "cpu_device_mesh",
-           "host_device_count_flag"]
+__all__ = ["shard_map", "tpu_compiler_params", "make_mesh",
+           "cpu_device_mesh", "host_device_count_flag", "too_few_devices"]
 
 
 # ---------------------------------------------------------------------------
-# shard_map: jax.shard_map (>= 0.6) vs jax.experimental.shard_map (<= 0.5)
+# shard_map
 # ---------------------------------------------------------------------------
 
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_TAKES_CHECK_REP = False
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHARD_MAP_TAKES_CHECK_REP = True
+def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
+    """``jax.shard_map`` with the call sites' ``check_rep`` spelling.
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-robust ``shard_map``.
-
-    ``check_rep=False`` is portable here: honoured by the legacy
-    experimental impl, silently dropped on the modern ``jax.shard_map``
-    (which renamed the knob). Pass it only at call sites whose traced body
-    the legacy replication checker cannot handle (it predates some
-    primitives, e.g. ``checkpoint_name``'s, and rejects them with
-    ``NotImplementedError: No replication rule``); everywhere else keep the
-    checker on — it catches out_specs that claim replication that was never
-    established.
+    ``check_rep=False`` maps to ``check_vma=False``. Pass it at call sites
+    whose traced body holds a ``pallas_call``: the varying-manual-axes
+    checker needs a ``vma`` on every ``out_shape``, which a kernel's
+    ``jax.ShapeDtypeStruct`` does not carry, and tracing then fails with
+    "``vma`` on ``jax.ShapeDtypeStruct`` must not be ``None``". Everywhere
+    else keep the checker on — it catches out_specs that claim
+    replication that was never established.
     """
-    if not _SHARD_MAP_TAKES_CHECK_REP:
-        kwargs.pop("check_rep", None)
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types.
+
+    ``jax.make_mesh`` now defaults to Explicit axes, which
+    ``with_sharding_constraint`` rejects; the model stack constrains
+    activations by ``PartitionSpec`` and needs the Auto (GSPMD) axes.
+    """
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 # ---------------------------------------------------------------------------
-# Pallas-TPU compiler params: CompilerParams (new) vs TPUCompilerParams (old)
+# Pallas-TPU compiler params
 # ---------------------------------------------------------------------------
-
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def tpu_compiler_params(*, dimension_semantics: Optional[Sequence[str]] = None,
                         **kwargs):
-    """Build the Pallas-TPU compiler-params struct under either name.
+    """Build the Pallas-TPU compiler-params struct.
 
     ``dimension_semantics`` is the tuple of per-grid-axis annotations
     ("parallel" / "arbitrary") every kernel in this repo passes; further
@@ -79,7 +75,7 @@ def tpu_compiler_params(*, dimension_semantics: Optional[Sequence[str]] = None,
     """
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return _COMPILER_PARAMS_CLS(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -96,13 +92,25 @@ def cpu_device_mesh(n: int, axis: str = "p") -> Mesh:
     """A 1D ``Mesh`` over the first ``n`` visible devices.
 
     This is the ring-setup used by the shard_map SpGEMM executor and the
-    multi-device subprocess tests. Raises with the exact XLA flag to set
-    when the process was started with fewer devices than requested.
+    multi-device subprocess tests. Raises when the process sees fewer
+    devices than requested (see :func:`too_few_devices`).
     """
     devs = jax.devices()
     if len(devs) < n:
-        raise ValueError(
-            f"need {n} devices, have {len(devs)}; relaunch with "
-            f"XLA_FLAGS={host_device_count_flag(n)} in the environment "
-            "(jax locks the device count at first init)")
+        raise ValueError(too_few_devices(n, len(devs)))
     return Mesh(np.array(devs[:n]), (axis,))
+
+
+def too_few_devices(need: int, have: int, what: str = "") -> str:
+    """The error text for a mesh that needs more devices than are visible.
+
+    Only the CPU backend can fake devices, so only there does the text
+    name the XLA flag to relaunch with; on an accelerator the fix is a
+    smaller geometry or a larger slice."""
+    msg = f"need {need} devices{what}, have {have} " \
+          f"({jax.default_backend()})"
+    if jax.default_backend() == "cpu":
+        msg += (f"; relaunch with XLA_FLAGS={host_device_count_flag(need)} "
+                "in the environment (jax locks the device count at first "
+                "init)")
+    return msg

@@ -237,20 +237,18 @@ def run_schedule(stack_a, stack_b, a_slot, b_slot, c_slot, flags, *,
 def device_grid_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """A mesh of the first ``prod(shape)`` visible devices, reshaped to
     ``shape`` with named ``axes`` (the n-d generalization of
-    ``repro.compat.cpu_device_mesh``). Raises with the exact XLA flag to
-    set when the process has fewer devices."""
+    ``repro.compat.cpu_device_mesh``). Raises when the process has fewer
+    devices (see ``repro.compat.too_few_devices``)."""
     import jax
     from jax.sharding import Mesh
 
-    from ..compat import host_device_count_flag
+    from ..compat import too_few_devices
 
     need = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) < need:
-        raise ValueError(
-            f"need {need} devices for a {shape} mesh, have {len(devs)}; "
-            f"relaunch with XLA_FLAGS={host_device_count_flag(need)} in the "
-            "environment (jax locks the device count at first init)")
+        raise ValueError(too_few_devices(need, len(devs),
+                                         f" for a {shape} mesh"))
     return Mesh(np.array(devs[:need]).reshape(shape), axes)
 
 

@@ -129,18 +129,18 @@ def _make_min_plus() -> Semiring:
     def _mp_matmul(a, b):
         # (i,k)+(k,j) min over k — tropical product of dense tiles.
         # Broadcast form: fine for the batched jnp reference engine on small
-        # tiles; the Pallas kernel uses the fori_loop combine below to avoid
+        # tiles; the Pallas kernel uses the rank-1 combine below to avoid
         # the O(bs^3) VMEM intermediate.
         return jnp.min(a[..., :, :, None] + b[..., None, :, :], axis=-2)
 
     def _mp_tile_combine(acc, a, b):
         # VPU formulation: stream rank-1 (column + row) updates, keeping
-        # every intermediate at (bs, bs)
-        def body(k, acc):
-            col = jax.lax.dynamic_slice_in_dim(a, k, 1, axis=1)  # (bs, 1)
-            row = jax.lax.dynamic_slice_in_dim(b, k, 1, axis=0)  # (1, bs)
-            return jnp.minimum(acc, col + row)
-        return jax.lax.fori_loop(0, a.shape[-1], body, acc)
+        # every intermediate at (bs, bs). The loop is unrolled over static
+        # slices: the TPU kernel compiler has no lowering for a lane
+        # dynamic_slice, which a fori_loop over k would need.
+        for k in range(a.shape[-1]):
+            acc = jnp.minimum(acc, a[:, k:k + 1] + b[k:k + 1, :])
+        return acc
 
     return Semiring(
         name="min_plus",

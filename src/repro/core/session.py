@@ -45,10 +45,15 @@ stage (plan / compile / execute / repack) runs under seeded-jitter
 exponential-backoff retries; a stage that stays broken walks the
 **degradation ladder** — engine fallback pallas→jnp, then algorithm
 downgrade 3d→2d→1d — and every rung is bitwise oracle-equivalent, so a
-degraded answer is still *the* answer. Cached entries whose stage fails
-are quarantined (dropped + device buffers released) and a per-key circuit
-breaker stops re-planning a key that keeps failing. Whatever escapes the
-ladder is a typed :class:`SpGEMMError`; bare ``RuntimeError`` never leaks.
+degraded answer is still *the* answer. The ladder is for faults of the
+device and its runtime only: a program that fails to trace, lower or
+compile raises :class:`CompileError` at once — no retry, no lower rung —
+because the same program would fail the same way again, and a lower rung
+would serve a broken product path on the reference engine unnoticed.
+Cached entries whose stage fails are quarantined (dropped + device
+buffers released) and a per-key circuit breaker stops re-planning a key
+that keeps failing. Whatever escapes the ladder is a typed
+:class:`SpGEMMError`; bare ``RuntimeError`` never leaks.
 """
 
 from __future__ import annotations
@@ -64,8 +69,9 @@ from ..runtime.fault_tolerance import RetryPolicy, with_retries
 from .device_common import SESSION_STATS, resolve_engine
 from .semiring import PLUS_TIMES, Semiring
 from .sparse import CSC
-from .validate import (DeviceExecError, SpGEMMError, ValidationError,
-                       validate_matmul_operands, wrap_stage_error)
+from .validate import (CompileError, DeviceExecError, SpGEMMError,
+                       ValidationError, validate_matmul_operands,
+                       wrap_stage_error)
 
 __all__ = ["SpGEMMSession", "session_or_new", "as_payload_dtype",
            "structure_fingerprint", "values_fingerprint", "ALGORITHMS",
@@ -185,6 +191,8 @@ class SpGEMMSession:
         plan_seconds   : host planning time spent by THIS call (0.0 on hit)
         comm_bytes_planned / comm_bytes_padded / messages / dense_flops :
                          the executed plan's stats surface
+        plan_stats     : the executed plan's whole ``stats`` dict (tile,
+                         product and slot counts)
         algorithm      : the algorithm rung that actually served the call
         engine         : the engine rung that actually served the call
         requested_algorithm : what the caller asked for (== algorithm
@@ -364,15 +372,23 @@ class SpGEMMSession:
         return plan, decode_summa_output, repack_summa_payloads
 
     def _compile(self, plan, algorithm: str, engine: str):
-        """Trace + compile the shard_map body (the ``compile`` stage);
-        returns (fn, device args)."""
+        """Place the plan and trace + lower + compile the shard_map body
+        ahead of time (the ``compile`` stage); returns (compiled
+        executable, device args). A failure of the program itself raises
+        :class:`CompileError`, which is neither retried nor laddered."""
         from .spgemm_1d_device import compile_ring
         from .spgemm_2d_device import compile_summa
 
         compiler = compile_ring if algorithm == "1d" else compile_summa
         fn, args = compiler(plan, engine=engine, interpret=self.interpret,
                             trace_probe=self._count_trace)
-        return fn, list(args)
+        try:
+            compiled = fn.lower(*args).compile()
+        except Exception as e:
+            raise CompileError(f"{type(e).__name__}: {e}", stage="compile",
+                               context={"algorithm": algorithm,
+                                        "engine": engine}) from e
+        return compiled, list(args)
 
     # ---- the one public multiply ------------------------------------------
 
@@ -439,12 +455,14 @@ class SpGEMMSession:
                 c, info = self._run_rung(a, b, alg_r, eng_r, algorithm,
                                          nparts, grid, layers, bs, nblocks,
                                          semiring, dtype, chunk, tenant)
-            except ValidationError:
+            except (ValidationError, CompileError):
                 # an ingress rejection (e.g. a dtype-mismatched values-only
                 # repack) is deterministic: every rung would refuse it the
                 # same way — and a colder rung would *accept* it by planning
                 # fresh with the silent cast the rejection exists to stop.
-                # The ladder is for device/stage failures, not bad requests.
+                # A program that does not compile is a bug a lower rung
+                # would hide. The ladder is for device faults, not for
+                # bad requests or broken programs.
                 raise
             except SpGEMMError as e:
                 last_err = e
@@ -460,7 +478,8 @@ class SpGEMMSession:
                 plan_seconds=info["plan_seconds"],
                 comm_bytes_planned=s["comm_bytes_planned"],
                 comm_bytes_padded=s["comm_bytes_padded"],
-                messages=s["messages"], dense_flops=s["dense_flops"])
+                messages=s["messages"], dense_flops=s["dense_flops"],
+                plan_stats=dict(s))
             return c
         raise last_err
 
@@ -577,10 +596,12 @@ class SpGEMMSession:
                 return entry.decode(entry.plan, out)
 
             c = self._stage("execute", do_execute, ctx)
-        except ValidationError:
+        except (ValidationError, CompileError):
             # ingress rejection of a malformed request: the cached entry is
             # healthy and untouched — quarantining it (or bumping its
-            # breaker) would punish the cache for the caller's operand
+            # breaker) would punish the cache for the caller's operand. A
+            # compile failure left nothing cached, and an open breaker
+            # would turn it into a laddered DeviceExecError on the next call
             raise
         except SpGEMMError:
             self._record_failure(key)

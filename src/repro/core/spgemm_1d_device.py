@@ -72,8 +72,9 @@ from .semiring import PLUS_TIMES, Semiring
 from .sparse import CSC
 
 __all__ = ["DeviceSpGEMMPlan", "build_device_plan", "compile_ring",
-           "run_device_spgemm", "decode_ring_output", "payload_need_maps",
-           "repack_ring_payloads", "segment_ring_schedule", "ENGINES"]
+           "ring_program", "ring_args", "run_device_spgemm",
+           "decode_ring_output", "payload_need_maps", "repack_ring_payloads",
+           "segment_ring_schedule", "ENGINES"]
 
 
 # ---------------------------------------------------------------------------
@@ -660,24 +661,40 @@ def compile_ring(plan: DeviceSpGEMMPlan,
     trace time only — the session uses it to assert zero retraces on
     cache hits.
     """
-    engine = resolve_engine(engine)
     check_plan_semiring(plan.semiring, semiring)
     if mesh is None:
         mesh = cpu_device_mesh(plan.nparts, axis)
 
+    fn = ring_program(plan, mesh, axis, engine, interpret, trace_probe)
     sharded = NamedSharding(mesh, P(axis))
-    args = [jax.device_put(x, sharded) for x in (
-        plan.a_tiles, plan.b_tiles, plan.send_slots,
-        plan.a_slot, plan.b_slot, plan.c_slot, plan.flags)]
+    return fn, [jax.device_put(x, sharded) for x in ring_args(plan)]
 
-    body = _make_step_fn(plan, axis, engine, interpret, trace_probe)
-    # check_rep=False: the legacy replication checker has no rule for
-    # pallas_call (see repro.compat.shard_map); nothing here is replicated.
-    fn = jax.jit(shard_map(
+
+def ring_args(plan: DeviceSpGEMMPlan) -> Tuple[np.ndarray, ...]:
+    """The host arrays :func:`ring_program` takes, in order; each has the
+    ring's part axis leading."""
+    return (plan.a_tiles, plan.b_tiles, plan.send_slots,
+            plan.a_slot, plan.b_slot, plan.c_slot, plan.flags)
+
+
+def ring_program(plan: DeviceSpGEMMPlan, mesh: Mesh,
+                 axis: str = "p", engine: str = "auto",
+                 interpret: Optional[bool] = None,
+                 trace_probe: Optional[callable] = None):
+    """The jitted shard_map ring over ``mesh``, placing nothing.
+
+    Takes :func:`ring_args` sharded ``P(axis)`` over ``mesh``. Unlike
+    :func:`compile_ring`, which places the plan first, it lets a caller
+    lower and compile the ring for devices it cannot place arrays on, such
+    as a described TPU topology, from shapes alone."""
+    body = _make_step_fn(plan, axis, resolve_engine(engine), interpret,
+                         trace_probe)
+    # check_rep=False: the kernel's out_shape carries no vma (see
+    # repro.compat.shard_map); nothing here is replicated.
+    return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(P(axis),) * 7,
         out_specs=P(axis), check_rep=False))
-    return fn, args
 
 
 def decode_ring_output(plan: DeviceSpGEMMPlan, out: np.ndarray) -> CSC:

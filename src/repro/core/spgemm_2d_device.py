@@ -507,10 +507,10 @@ def compile_summa(plan: SummaDevicePlan,
         plan.c_slot, plan.flags, plan.visit)]
 
     body = _make_body(plan, axes, engine, interpret, trace_probe)
-    # check_rep=False: the legacy replication checker has no rule for
-    # pallas_call (see repro.compat.shard_map); the layer reduce makes the
-    # output replicated over the layer axis, which out_specs deliberately
-    # do not claim.
+    # check_rep=False: the kernel's out_shape carries no vma (see
+    # repro.compat.shard_map); the layer reduce makes the output
+    # replicated over the layer axis, which out_specs deliberately do not
+    # claim.
     fn = jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(P(*axes),) * 7,

@@ -21,6 +21,9 @@ The error taxonomy (see also ROADMAP "hardened-runtime contract"):
     ├── ValidationError         — malformed operand at session ingress
     ├── PlanError               — host planning / packing / geometry failed
     └── DeviceExecError         — compile / execute / repack failed on device
+        └── CompileError        — the engine's program failed to trace,
+                                  lower or compile (deterministic: never
+                                  retried, never served by a lower rung)
 
 No bare ``RuntimeError`` may escape the session: anything a stage raises
 that is not already an ``SpGEMMError`` is wrapped into ``PlanError`` (plan
@@ -40,7 +43,7 @@ from .sparse import CSC
 
 __all__ = [
     "SpGEMMError", "ValidationError", "PlanError", "DeviceExecError",
-    "wrap_stage_error", "validate_csc", "validate_blocksparse",
+    "CompileError", "wrap_stage_error", "validate_csc", "validate_blocksparse",
     "validate_matmul_operands",
 ]
 
@@ -82,6 +85,15 @@ class PlanError(SpGEMMError):
 
 class DeviceExecError(SpGEMMError):
     """Compilation or device execution (including payload repack) failed."""
+
+
+class CompileError(DeviceExecError):
+    """Tracing, lowering or compiling the engine's program failed.
+
+    The failure is a property of the program and the plan's shapes, not of
+    the device's state: a retry fails the same way, and serving the call on
+    a lower rung (the ``jnp`` reference engine, another algorithm) would
+    hide a broken product path behind a correct answer."""
 
 
 # which taxonomy class wraps an unexpected failure of each pipeline stage

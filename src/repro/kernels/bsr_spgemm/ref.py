@@ -10,9 +10,8 @@ __all__ = ["bsr_spgemm_ref"]
 
 
 def bsr_spgemm_ref(a_tiles, b_tiles, a_slot, b_slot, c_slot,
-                   *, nc: int, out_dtype=jnp.float32,
-                   semiring: Semiring = PLUS_TIMES, seg_start: int = 0,
-                   seg_len: int = None):
+                   *, nc: int, semiring: Semiring = PLUS_TIMES,
+                   seg_start: int = 0, seg_len: int = None):
     """Segment-reduce formulation of the same schedule.
 
     C[c_slot[s]] (+)= A[a_slot[s]] ⊗ B[b_slot[s]]  for every product s,
@@ -37,10 +36,10 @@ def bsr_spgemm_ref(a_tiles, b_tiles, a_slot, b_slot, c_slot,
     b_slot = b_slot[seg_start:seg_start + seg_len]
     c_slot = c_slot[seg_start:seg_start + seg_len]
     if len(a_slot) == 0:
-        return jnp.full((max(nc, 1), bs, bs), semiring.zero, dtype=out_dtype)
+        return jnp.full((max(nc, 1), bs, bs), semiring.zero,
+                        dtype=jnp.float32)
     prods = semiring.jnp_matmul(
         a_tiles[a_slot].astype(jnp.float32),
         b_tiles[b_slot].astype(jnp.float32),
     )
-    out = semiring.jnp_segment_reduce(prods, c_slot, nc)
-    return out.astype(out_dtype)
+    return semiring.jnp_segment_reduce(prods, c_slot, nc)

@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import jax
 
+from ..compat import make_mesh
+
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
@@ -12,7 +14,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -20,5 +22,4 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = data * model
     devs = jax.devices()
     assert len(devs) >= n, (len(devs), n)
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devs[:n])
+    return make_mesh((data, model), ("data", "model"), devices=devs[:n])

@@ -20,6 +20,7 @@ import numpy as np
 from ..core.semiring import by_name
 from ..core.sparse import banded_clustered
 from ..serve import ServicePolicy, SpGEMMRequest, SpGEMMService
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -40,6 +41,7 @@ def main(argv=None):
                     help="global device byte budget in MiB")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     g = banded_clustered(args.n, max(args.n // 40, 8), 6.0, seed=args.seed)
     g.data[:] = np.rint(2 * g.data)

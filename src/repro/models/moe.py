@@ -204,8 +204,8 @@ def _moe_shard_map(params, cfg: ModelConfig, x, rules,
                  {"moe/routed_tokens": P(), "moe/capacity_slots": P(),
                   "moe/dropped": P()})
 
-    # check_rep off: the body traces checkpoint_name, which the legacy
-    # replication checker has no rule for (see repro.compat.shard_map)
+    # check_rep off: with use_kernel the body holds the grouped-GEMM
+    # pallas_call, whose out_shape carries no vma (see repro.compat.shard_map)
     fn = shard_map(local, mesh=rules.mesh,
                    in_specs=in_specs, out_specs=out_specs,
                    check_rep=False)

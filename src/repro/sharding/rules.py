@@ -188,7 +188,23 @@ def shard(x, *logical: Optional[str]):
             spec.append(rules.fsdp)
         else:  # pragma: no cover
             raise ValueError(f"unknown logical axis {name!r}")
-    return jax.lax.with_sharding_constraint(x, P(*spec))
+    return jax.lax.with_sharding_constraint(x, P(*_dedupe_axes(spec)))
+
+
+def _dedupe_axes(spec):
+    """A mesh axis may shard at most one dim: a later dim drops any axis an
+    earlier dim already uses (batch and fsdp can both map to 'data')."""
+    used = set()
+    out = []
+    for entry in spec:
+        axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
+        keep = tuple(a for a in axes if a not in used)
+        used.update(keep)
+        if not keep:
+            out.append(None)
+        else:
+            out.append(keep[0] if isinstance(entry, str) else keep)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,18 @@ accumulate in ``failures_history``.
 import json
 import types
 
+import pytest
+
 import benchmarks.run as bench_run
 from benchmarks.common import Csv
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache(monkeypatch):
+    # main() turns on the persistent compile cache for the whole process;
+    # the stub benches compile nothing, and later tests on this worker
+    # must not write into the checkout's cache
+    monkeypatch.setattr(bench_run, "enable_compile_cache", lambda: None)
 
 
 def _stub(name, rows, fail=False):

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 from repro.core import (BOOL_OR_AND, MIN_PLUS, PLUS_TIMES, erdos_renyi,
                         banded_clustered, from_coo, from_dense, spgemm)
-from repro.core.blocksparse import build_schedule, from_csc
+from repro.core.blocksparse import build_schedule, flags_from_c_slot, from_csc
 from repro.kernels.bsr_spgemm import (bsr_spgemm_pallas, bsr_spgemm_ref,
                                       local_spgemm_device, schedule_flags)
 
@@ -172,6 +172,38 @@ def test_kernel_vs_ref_dtypes(dtype):
     tol = 1e-5 if dtype == jnp.float32 else 1e-1
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("semiring", [PLUS_TIMES, BOOL_OR_AND, MIN_PLUS],
+                         ids=lambda s: s.name)
+@pytest.mark.parametrize("window,seg_start", [(2, 0), (4, 3), (10 ** 6, 2)])
+def test_kernel_windows_match_ref(semiring, window, seg_start):
+    """Cutting the schedule into windows of ``window`` products (one
+    ``pallas_call`` each, runs of one output tile split across cuts) gives
+    the single-launch answer bitwise, also from a segment offset."""
+    rng = np.random.default_rng(17)
+    d = np.rint(3 * ((rng.random((24, 24)) < 0.5)
+                     * rng.standard_normal((24, 24))))
+    bsa = from_csc(from_dense(d), bs=8, fill=semiring.zero)
+    sched = build_schedule(bsa, bsa)
+    n = sched.nprod - seg_start
+    # 3x3 tile grid: every output tile has a run of 3 products, so windows
+    # of 2 and 4 cut through runs
+    assert (sched.nprod, sched.nc) == (27, 9)
+    tiles = jnp.asarray(bsa.tiles)
+    sl = [jnp.asarray(x) for x in (sched.a_slot, sched.b_slot, sched.c_slot)]
+    # a segment's flags are its own: its first product opens its run
+    flags = np.concatenate([flags_from_c_slot(sched.c_slot[:seg_start]),
+                            flags_from_c_slot(sched.c_slot[seg_start:])])
+    out_k = bsr_spgemm_pallas(tiles, tiles, *sl, jnp.asarray(flags),
+                              nprod=n, nc=sched.nc, bs=8, interpret=True,
+                              semiring=semiring, seg_start=seg_start,
+                              window=window)
+    out_r = bsr_spgemm_ref(tiles, tiles, *sl, nc=sched.nc,
+                           semiring=semiring, seg_start=seg_start, seg_len=n)
+    visited = np.unique(sched.c_slot[seg_start:])
+    np.testing.assert_array_equal(np.asarray(out_k)[visited],
+                                  np.asarray(out_r)[visited])
 
 
 def test_empty_schedule():

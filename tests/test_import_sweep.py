@@ -77,3 +77,17 @@ def test_compat_is_the_only_drift_point():
     assert mesh.shape["p"] == 1
     with pytest.raises(ValueError, match="xla_force_host_platform"):
         compat.cpu_device_mesh(10_000)
+
+
+@pytest.mark.parametrize("backend,names_flag", [("cpu", True),
+                                                ("tpu", False)])
+def test_too_few_devices_names_the_flag_only_on_cpu(monkeypatch, backend,
+                                                    names_flag):
+    """Only the CPU backend can fake devices: on a chip the error must not
+    send the user to the host-device flag."""
+    from repro import compat
+
+    monkeypatch.setattr(compat.jax, "default_backend", lambda: backend)
+    msg = compat.too_few_devices(4, 1)
+    assert msg.startswith(f"need 4 devices, have 1 ({backend})")
+    assert ("xla_force_host_platform" in msg) is names_flag

@@ -49,6 +49,7 @@ def test_decode_matches_prefill(arch):
 
 SHARD_MAP_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.compat import make_mesh
     from repro.configs import smoke_config
     from repro.models.moe import moe_apply, moe_init
     from repro.sharding import ShardingRules, use_rules
@@ -59,7 +60,7 @@ SHARD_MAP_SCRIPT = textwrap.dedent("""
 
     y_ref, aux_ref, m_ref = moe_apply(params, cfg, x, use_kernel=False)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = ShardingRules.for_mesh(mesh, profile="ep_sharded")
     with mesh, use_rules(rules):
         y_sm, aux_sm, m_sm = jax.jit(

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.compat import make_mesh
 from repro.configs import SHAPES, get_config
 from repro.launch.roofline import (bytes_model, collective_bytes_from_hlo,
                                    model_flops)
@@ -44,7 +45,7 @@ def test_shard_noop_without_rules():
 
 def test_shard_divisibility_guard():
     """Indivisible dims must not be constrained (gemma2 8 heads / tp16)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rules = ShardingRules(batch=("data",), fsdp="data", tp=None, sp=None,
                           tp_size=16, batch_size=1)
     with mesh, use_rules(rules):

@@ -71,7 +71,7 @@ RS004_SCOPE = (
 
 SESSION_ONLY_NAMES = frozenset({
     "build_device_plan", "build_summa_plan", "build_summa3d_plan",
-    "compile_ring", "compile_summa", "compile_summa3d",
+    "compile_ring", "ring_program", "compile_summa", "compile_summa3d",
 })
 
 # ---------------------------------------------------------------------------
