@@ -38,6 +38,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..runtime.spans import span
 from .blocksparse import BlockSparse, flags_from_c_slot, from_csc
 from .plan import Partition1D
 from .semiring import Semiring
@@ -280,12 +281,16 @@ def decode_tiles(out: np.ndarray, c_rows: np.ndarray, c_cols: np.ndarray,
         col_off = np.zeros(D, dtype=np.int64)
     if col_lim is None:
         col_lim = np.full(D, out_shape[1], dtype=np.int64)
-    valid_tile = np.arange(nc_max)[None, :] < np.asarray(c_counts)[:, None]
-    out = np.where(valid_tile[:, :, None, None], out,
-                   out.dtype.type(semiring.zero))
-    ii, tt, rr, cc = np.nonzero(semiring.prune_mask(out))
-    vals = out[ii, tt, rr, cc]
-    rows_g = rr + c_rows[ii, tt].astype(np.int64) * bs
-    cols_g = cc + c_cols[ii, tt].astype(np.int64) * bs + col_off[ii]
-    keep = (rows_g < out_shape[0]) & (cols_g < col_lim[ii])
-    return from_coo(rows_g[keep], cols_g[keep], vals[keep], out_shape)
+    with span("spgemm.decode.prune"):
+        valid_tile = (np.arange(nc_max)[None, :]
+                      < np.asarray(c_counts)[:, None])
+        out = np.where(valid_tile[:, :, None, None], out,
+                       out.dtype.type(semiring.zero))
+        ii, tt, rr, cc = np.nonzero(semiring.prune_mask(out))
+        vals = out[ii, tt, rr, cc]
+        rows_g = rr + c_rows[ii, tt].astype(np.int64) * bs
+        cols_g = cc + c_cols[ii, tt].astype(np.int64) * bs + col_off[ii]
+        keep = (rows_g < out_shape[0]) & (cols_g < col_lim[ii])
+        rows_g, cols_g, vals = rows_g[keep], cols_g[keep], vals[keep]
+    with span("spgemm.decode.assemble", nnz=len(vals)):
+        return from_coo(rows_g, cols_g, vals, out_shape)

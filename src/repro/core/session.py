@@ -66,6 +66,7 @@ from typing import Callable, List, Optional, Tuple
 import numpy as np
 
 from ..runtime.fault_tolerance import RetryPolicy, with_retries
+from ..runtime.spans import span
 from .device_common import SESSION_STATS, resolve_engine
 from .semiring import PLUS_TIMES, Semiring
 from .sparse import CSC
@@ -74,8 +75,8 @@ from .validate import (CompileError, DeviceExecError, SpGEMMError,
                        wrap_stage_error)
 
 __all__ = ["SpGEMMSession", "session_or_new", "as_payload_dtype",
-           "structure_fingerprint", "values_fingerprint", "ALGORITHMS",
-           "DOWNGRADE"]
+           "structure_fingerprint", "values_fingerprint",
+           "fingerprint_nbytes", "ALGORITHMS", "DOWNGRADE"]
 
 ALGORITHMS = ("1d", "2d", "3d")
 
@@ -105,6 +106,28 @@ def values_fingerprint(mat: CSC) -> bytes:
     h = hashlib.blake2b(digest_size=16)
     h.update(mat.data.tobytes())
     return h.digest()
+
+
+def fingerprint_nbytes(*mats: CSC) -> int:
+    """Bytes that the structure and values fingerprints of ``mats`` hash
+    (a span counter: O(1))."""
+    return sum(16 + m.indptr.nbytes + m.indices.nbytes + m.data.nbytes
+               for m in mats)
+
+
+def _stored_entries(*mats) -> int:
+    """Stored entries of the operands, a span counter read before
+    validation: O(1), and 0 for an operand whose data is no array."""
+    return sum(m.data.size for m in mats
+               if isinstance(getattr(m, "data", None), np.ndarray))
+
+
+def _tile_slots(plan, a: Optional[CSC], b: Optional[CSC]) -> int:
+    """Payload tile slots a values-only repack refills for the changed
+    sides (a span counter: O(1))."""
+    return sum(int(np.prod(stack.shape[:-2]))
+               for m, stack in ((a, plan.a_tiles), (b, plan.b_tiles))
+               if m is not None)
 
 
 def as_payload_dtype(mat: CSC, dtype=np.float32) -> CSC:
@@ -360,16 +383,17 @@ class SpGEMMSession:
         from .spgemm_2d_device import (build_summa_plan, decode_summa_output,
                                        repack_summa_payloads)
 
-        if algorithm == "1d":
-            plan = build_device_plan(
-                a, b, nparts, bs=bs, nblocks=nblocks, dtype=dtype,
-                semiring=semiring, a_blockize_cache=self._blockize_cache,
-                chunk=chunk)
-            return plan, decode_ring_output, repack_ring_payloads
-        plan = build_summa_plan(
-            a, b, grid=grid, layers=layers if algorithm == "3d" else 1,
-            bs=bs, dtype=dtype, semiring=semiring)
-        return plan, decode_summa_output, repack_summa_payloads
+        with span("spgemm.session.plan"):
+            if algorithm == "1d":
+                plan = build_device_plan(
+                    a, b, nparts, bs=bs, nblocks=nblocks, dtype=dtype,
+                    semiring=semiring,
+                    a_blockize_cache=self._blockize_cache, chunk=chunk)
+                return plan, decode_ring_output, repack_ring_payloads
+            plan = build_summa_plan(
+                a, b, grid=grid, layers=layers if algorithm == "3d" else 1,
+                bs=bs, dtype=dtype, semiring=semiring)
+            return plan, decode_summa_output, repack_summa_payloads
 
     def _compile(self, plan, algorithm: str, engine: str):
         """Place the plan and trace + lower + compile the shard_map body
@@ -380,14 +404,17 @@ class SpGEMMSession:
         from .spgemm_2d_device import compile_summa
 
         compiler = compile_ring if algorithm == "1d" else compile_summa
-        fn, args = compiler(plan, engine=engine, interpret=self.interpret,
-                            trace_probe=self._count_trace)
-        try:
-            compiled = fn.lower(*args).compile()
-        except Exception as e:
-            raise CompileError(f"{type(e).__name__}: {e}", stage="compile",
-                               context={"algorithm": algorithm,
-                                        "engine": engine}) from e
+        with span("spgemm.session.compile"):
+            fn, args = compiler(plan, engine=engine,
+                                interpret=self.interpret,
+                                trace_probe=self._count_trace)
+            try:
+                compiled = fn.lower(*args).compile()
+            except Exception as e:
+                raise CompileError(f"{type(e).__name__}: {e}",
+                                   stage="compile",
+                                   context={"algorithm": algorithm,
+                                            "engine": engine}) from e
         return compiled, list(args)
 
     # ---- the one public multiply ------------------------------------------
@@ -434,7 +461,9 @@ class SpGEMMSession:
         self.stats["calls"] += 1
         if self.validate:
             try:
-                validate_matmul_operands(a, b, semiring=semiring)
+                with span("spgemm.session.validate",
+                          nnz=_stored_entries(a, b)):
+                    validate_matmul_operands(a, b, semiring=semiring)
             except ValidationError:
                 self.stats["validation_failures"] += 1
                 raise
@@ -499,6 +528,10 @@ class SpGEMMSession:
             geom = (nparts if requested == "1d" else grid * grid,)
         else:
             geom = (grid, layers if algorithm == "3d" else 1)
+        with span("spgemm.session.fingerprint",
+                  hashed_bytes=fingerprint_nbytes(a, b)):
+            struct_fp = (structure_fingerprint(a), structure_fingerprint(b))
+            val_fp = (values_fingerprint(a), values_fingerprint(b))
         # nblocks and chunk are 1D-ring knobs (Algorithm-2 fetch grouping /
         # the double-buffered chunk size); the SUMMA planners have neither,
         # so they must not split byte-identical 2d/3d plans into distinct
@@ -506,8 +539,7 @@ class SpGEMMSession:
         key = (algorithm, geom, bs,
                nblocks if algorithm == "1d" else None,
                chunk if algorithm == "1d" else None,
-               semiring.name, engine, np.dtype(dtype).str,
-               structure_fingerprint(a), structure_fingerprint(b))
+               semiring.name, engine, np.dtype(dtype).str, *struct_fp)
         ctx = {"algorithm": algorithm, "engine": engine,
                "requested_algorithm": requested}
         failures = self._quarantine.get(key, 0)
@@ -523,7 +555,6 @@ class SpGEMMSession:
         plan_seconds = 0.0
         try:
             if hit:
-                val_fp = (values_fingerprint(a), values_fingerprint(b))
                 if val_fp != entry.val_fp:
                     # values-only repacks blockize straight into the plan's
                     # payload stacks; a dtype-mismatched operand would be
@@ -559,17 +590,23 @@ class SpGEMMSession:
                     # A mid-repack failure quarantines the entry, so a
                     # half-swapped payload stack can never serve a call.
                     def do_repack():
-                        new_a, new_b = entry.repack(
-                            entry.plan,
-                            a if val_fp[0] != entry.val_fp[0] else None,
-                            b if val_fp[1] != entry.val_fp[1] else None)
+                        side_a = a if val_fp[0] != entry.val_fp[0] else None
+                        side_b = b if val_fp[1] != entry.val_fp[1] else None
+                        with span("spgemm.repack.blockize",
+                                  tiles=_tile_slots(entry.plan, side_a,
+                                                    side_b)):
+                            new_a, new_b = entry.repack(entry.plan, side_a,
+                                                        side_b)
                         import jax
-                        if new_a is not None:
-                            entry.args[0] = jax.device_put(
-                                new_a, entry.args[0].sharding)
-                        if new_b is not None:
-                            entry.args[1] = jax.device_put(
-                                new_b, entry.args[1].sharding)
+                        with span("spgemm.repack.h2d",
+                                  h2d_bytes=sum(x.nbytes for x in (
+                                      new_a, new_b) if x is not None)):
+                            if new_a is not None:
+                                entry.args[0] = jax.device_put(
+                                    new_a, entry.args[0].sharding)
+                            if new_b is not None:
+                                entry.args[1] = jax.device_put(
+                                    new_b, entry.args[1].sharding)
 
                     self._stage("repack", do_repack, ctx)
                     entry.val_fp = val_fp
@@ -587,12 +624,14 @@ class SpGEMMSession:
                     "compile",
                     lambda: self._compile(plan, algorithm, engine), ctx)
                 plan_seconds = time.perf_counter() - t0
-                entry = _Entry(plan, fn, args, decode, repack,
-                               (values_fingerprint(a),
-                                values_fingerprint(b)), owner=tenant)
+                entry = _Entry(plan, fn, args, decode, repack, val_fp,
+                               owner=tenant)
 
             def do_execute():
-                out = np.asarray(entry.fn(*entry.args))
+                with span("spgemm.execute.dispatch"):
+                    res = entry.fn(*entry.args)
+                with span("spgemm.execute.fetch", d2h_bytes=res.nbytes):
+                    out = np.asarray(res)
                 return entry.decode(entry.plan, out)
 
             c = self._stage("execute", do_execute, ctx)

@@ -153,6 +153,9 @@ def _launch_window(a_tiles, b_tiles, a_slot, b_slot, c_slot, flags, c_prev,
         # products that hit the same output tile must execute in order
         dimension_semantics=("arbitrary",),
         input_output_aliases=aliases,
+        # the launch's op name in a compiled program and its device trace
+        # (``bsr_spgemm_pallas.<k>``), whatever jitted function encloses it
+        name="bsr_spgemm_pallas",
     )(*operands)
 
 

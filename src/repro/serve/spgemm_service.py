@@ -60,11 +60,12 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.semiring import PLUS_TIMES, Semiring
-from ..core.session import (SpGEMMSession, structure_fingerprint,
-                            values_fingerprint)
+from ..core.session import (SpGEMMSession, fingerprint_nbytes,
+                            structure_fingerprint, values_fingerprint)
 from ..core.sparse import CSC
 from ..core.validate import SpGEMMError
 from ..runtime.fault_tolerance import CircuitBreaker
+from ..runtime.spans import span
 
 __all__ = ["SERVICE_STATS", "ServicePolicy", "SpGEMMRequest", "ServedResult",
            "SpGEMMService", "TenantOverloadError"]
@@ -314,10 +315,15 @@ class SpGEMMService:
         out, self._rejected = self._rejected, {}
 
         groups: "OrderedDict[tuple, list]" = OrderedDict()
-        for ticket, req in batch:
-            # coalescing off → every ticket is its own group
-            key = req.exec_key() if self.policy.coalesce else ("!", ticket)
-            groups.setdefault(key, []).append((ticket, req))
+        hashed = sum(fingerprint_nbytes(r.a, r.b) for _, r in batch) \
+            if self.policy.coalesce else 0
+        with span("spgemm.service.coalesce", requests=len(batch),
+                  hashed_bytes=hashed):
+            for ticket, req in batch:
+                # coalescing off → every ticket is its own group
+                key = req.exec_key() if self.policy.coalesce \
+                    else ("!", ticket)
+                groups.setdefault(key, []).append((ticket, req))
 
         for members in groups.values():
             t0 = self.clock()

@@ -20,6 +20,7 @@ program raises a typed error instead of serving the call on a lower rung.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +127,30 @@ def test_ring_compiles_on_4_described_chips(topo, chunk):
     text = fn.lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+def test_served_ring_names_its_kernel_launches(topo):
+    """The served ring program names each kernel launch itself
+    (``pallas_call(name=...)``), so every launch compiles to an op
+    ``bsr_spgemm_pallas.<k>``: the op name a device trace shows, whatever
+    jitted function encloses the kernel."""
+    a = banded_clustered(4096, 512, 16, seed=0).astype(np.float32)
+    plan = build_device_plan(a, a, nparts=1, bs=BS, semiring=MIN_PLUS)
+    mesh = Mesh(np.array(topo.devices[:1]), ("p",))
+    fn = ring_program(plan, mesh, interpret=False)
+    shard = NamedSharding(mesh, P("p"))
+    shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=shard)
+              for x in ring_args(plan)]
+    lowered = fn.lower(*shapes)
+    launches = lowered.as_text().count("@tpu_custom_call")
+    assert launches >= 1
+    assert lowered.as_text().count(
+        'kernel_name = "bsr_spgemm_pallas"') == launches
+    ops = [line.split(" = ", 1)[0].split()[-1].lstrip("%")
+           for line in lowered.compile().as_text().splitlines()
+           if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(ops) == launches
+    assert all(re.fullmatch(r"bsr_spgemm_pallas(\.\d+)*", op) for op in ops)
 
 
 def test_session_raises_on_a_program_that_does_not_compile(monkeypatch):
