@@ -58,6 +58,11 @@ class BlockSparse:
                 the semiring the tiles execute under (0.0 for plus-times /
                 bool, +inf for min-plus). Distinguishes "absent entry" from
                 "explicitly stored value equal to 0.0".
+    entry_pos : (nnz,) int64 flat index into ``tiles`` of each stored entry
+                of the source CSC, in its data order (set by
+                :func:`from_csc`; None for containers built otherwise) —
+                ``tiles.reshape(-1)[entry_pos] = data`` refills the payloads
+                for new values on the same structure.
     """
 
     tiles: np.ndarray
@@ -67,6 +72,7 @@ class BlockSparse:
     orig_shape: Tuple[int, int]
     bs: int
     fill: float = 0.0
+    entry_pos: Optional[np.ndarray] = None
 
     @property
     def ntiles(self) -> int:
@@ -159,7 +165,8 @@ def from_csc(a: CSC, bs: int = DEFAULT_BLOCK,
     # searchsorted — no per-nonzero Python dict probing
     slot = np.searchsorted(uniq_keys, key) if len(key) \
         else np.zeros(0, dtype=np.int64)
-    tiles[slot, rows % bs, cols % bs] = vals.astype(dtype)
+    pos = (slot * bs + rows % bs) * bs + cols % bs
+    tiles.reshape(-1)[pos] = vals.astype(dtype)
     return BlockSparse(
         tiles=tiles,
         tile_rows=(uniq_keys % gm).astype(np.int32),
@@ -168,6 +175,7 @@ def from_csc(a: CSC, bs: int = DEFAULT_BLOCK,
         orig_shape=(m, n),
         bs=bs,
         fill=fill,
+        entry_pos=pos,
     )
 
 

@@ -23,10 +23,13 @@ with three outcomes:
     executable + device-resident args;
   * **hit, same values** — run the cached executable as-is: zero host
     planning, zero retrace, zero payload transfer;
-  * **hit, new values** — the values-only path: re-blockize payloads on
-    the cached plan's partitions (``repack_ring_payloads`` /
-    ``repack_summa_payloads``), swap them into the cached device args, run
-    the same executable. Still zero planning and zero retrace.
+  * **hit, new values** — the values-only path: refill the payload
+    stacks and swap them into the cached device args, run the same
+    executable. The 1D ring sends only the new values
+    (``repack_ring_payloads``) and scatters them on the device through
+    slot maps kept with the plan (``RingValueScatter``, compiled with the
+    entry); the SUMMA engines re-blockize on the host
+    (``repack_summa_payloads``). Still zero planning and zero retrace.
 
 Any structure change, semiring change, engine change or geometry change is
 simply a different key — invalidation is by construction, not by mutation
@@ -170,32 +173,42 @@ class _Entry:
     serving layer) — budgets charge the creator even when other tenants'
     structure-identical requests later hit the same entry. ``nbytes`` is
     the device footprint of the entry's argument stacks, fixed at compile
-    time (values-only repacks swap same-shape payloads in place).
+    time (values-only repacks swap same-shape payloads in place), plus
+    the slot maps of its device scatter.
+
+    ``scatter`` is the 1D ring's values-only repack on the device
+    (:class:`~repro.core.spgemm_1d_device.RingValueScatter`), which writes
+    the values ``repack`` hands over into fresh stacks; None for the
+    SUMMA engines and for ring plans out of int32's reach, whose
+    ``repack`` hands over whole host-refilled stacks.
     """
 
-    __slots__ = ("plan", "fn", "args", "decode", "repack", "val_fp",
-                 "owner", "nbytes")
+    __slots__ = ("plan", "fn", "args", "decode", "repack", "scatter",
+                 "val_fp", "owner", "nbytes")
 
     def __init__(self, plan, fn, args: List, decode: Callable,
                  repack: Callable, val_fp: Tuple[bytes, bytes],
-                 owner: Optional[str] = None):
+                 owner: Optional[str] = None, scatter=None):
         self.plan = plan
         self.fn = fn
         self.args = args
         self.decode = decode
         self.repack = repack
+        self.scatter = scatter
         self.val_fp = val_fp
         self.owner = owner
-        self.nbytes = sum(int(getattr(x, "nbytes", 0)) for x in args)
+        self.nbytes = sum(int(getattr(x, "nbytes", 0)) for x in args) \
+            + (scatter.nbytes if scatter is not None else 0)
 
     def release(self) -> None:
         """Drop the device buffer references (the payload/schedule stacks in
-        ``args``) and the compiled executable so eviction actually returns
-        device memory — an evicted entry kept alive by a stray reference
-        must not pin its arrays."""
+        ``args``, the scatter's slot maps) and the compiled executables so
+        eviction actually returns device memory — an evicted entry kept
+        alive by a stray reference must not pin its arrays."""
         self.args = []
         self.fn = None
         self.repack = None
+        self.scatter = None
 
 
 class SpGEMMSession:
@@ -397,10 +410,11 @@ class SpGEMMSession:
 
     def _compile(self, plan, algorithm: str, engine: str):
         """Place the plan and trace + lower + compile the shard_map body
-        ahead of time (the ``compile`` stage); returns (compiled
-        executable, device args). A failure of the program itself raises
+        ahead of time (the ``compile`` stage), and for the 1D ring its
+        values-only scatter; returns (compiled executable, device args,
+        scatter or None). A failure of the program itself raises
         :class:`CompileError`, which is neither retried nor laddered."""
-        from .spgemm_1d_device import compile_ring
+        from .spgemm_1d_device import compile_ring, compile_ring_scatter
         from .spgemm_2d_device import compile_summa
 
         compiler = compile_ring if algorithm == "1d" else compile_summa
@@ -410,12 +424,14 @@ class SpGEMMSession:
                                 trace_probe=self._count_trace)
             try:
                 compiled = fn.lower(*args).compile()
+                scatter = compile_ring_scatter(plan, args[0].sharding) \
+                    if algorithm == "1d" else None
             except Exception as e:
                 raise CompileError(f"{type(e).__name__}: {e}",
                                    stage="compile",
                                    context={"algorithm": algorithm,
                                             "engine": engine}) from e
-        return compiled, list(args)
+        return compiled, list(args), scatter
 
     # ---- the one public multiply ------------------------------------------
 
@@ -587,26 +603,40 @@ class SpGEMMSession:
                     # only for the side(s) whose values actually changed
                     # (BC's backward sweep keeps the adjacency operand
                     # bit-identical while the frontier moves every level).
-                    # A mid-repack failure quarantines the entry, so a
-                    # half-swapped payload stack can never serve a call.
+                    # The 1D ring's repack hands over only the values,
+                    # which its scatter writes into fresh stacks on the
+                    # device; the SUMMA engines (and a ring plan past
+                    # int32's reach) hand over whole host-refilled stacks,
+                    # put as they are. The stacks are swapped in only once
+                    # all are placed, and a failure quarantines the entry,
+                    # so a half-swapped payload stack can never serve a
+                    # call.
                     def do_repack():
-                        side_a = a if val_fp[0] != entry.val_fp[0] else None
-                        side_b = b if val_fp[1] != entry.val_fp[1] else None
+                        sides = tuple(
+                            m if val_fp[i] != entry.val_fp[i] else None
+                            for i, m in enumerate((a, b)))
+                        scatter = entry.scatter
                         with span("spgemm.repack.blockize",
-                                  tiles=_tile_slots(entry.plan, side_a,
-                                                    side_b)):
-                            new_a, new_b = entry.repack(entry.plan, side_a,
-                                                        side_b)
-                        import jax
+                                  tiles=_tile_slots(entry.plan, *sides)):
+                            host = entry.repack(entry.plan, *sides)
                         with span("spgemm.repack.h2d",
-                                  h2d_bytes=sum(x.nbytes for x in (
-                                      new_a, new_b) if x is not None)):
-                            if new_a is not None:
-                                entry.args[0] = jax.device_put(
-                                    new_a, entry.args[0].sharding)
-                            if new_b is not None:
-                                entry.args[1] = jax.device_put(
-                                    new_b, entry.args[1].sharding)
+                                  h2d_bytes=sum(x.nbytes for x in host
+                                                if x is not None),
+                                  entries=0 if scatter is None
+                                  else _stored_entries(*(
+                                      m for m, x in zip(sides, host)
+                                      if x is not None))):
+                            if scatter is None:
+                                import jax
+                                placed = tuple(
+                                    None if x is None else jax.device_put(
+                                        x, entry.args[i].sharding)
+                                    for i, x in enumerate(host))
+                            else:
+                                placed = scatter(host)
+                        for i, x in enumerate(placed):
+                            if x is not None:
+                                entry.args[i] = x
 
                     self._stage("repack", do_repack, ctx)
                     entry.val_fp = val_fp
@@ -620,12 +650,12 @@ class SpGEMMSession:
                                        layers, bs, nblocks, semiring,
                                        dtype, chunk),
                     ctx)
-                fn, args = self._stage(
+                fn, args, scatter = self._stage(
                     "compile",
                     lambda: self._compile(plan, algorithm, engine), ctx)
                 plan_seconds = time.perf_counter() - t0
                 entry = _Entry(plan, fn, args, decode, repack, val_fp,
-                               owner=tenant)
+                               owner=tenant, scatter=scatter)
 
             def do_execute():
                 with span("spgemm.execute.dispatch"):

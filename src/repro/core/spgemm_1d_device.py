@@ -74,7 +74,14 @@ from .sparse import CSC
 __all__ = ["DeviceSpGEMMPlan", "build_device_plan", "compile_ring",
            "ring_program", "ring_args", "run_device_spgemm",
            "decode_ring_output", "payload_need_maps", "repack_ring_payloads",
-           "segment_ring_schedule", "ENGINES"]
+           "segment_ring_schedule", "slot_map", "refill_ring_stacks",
+           "ring_values", "RingValueScatter", "compile_ring_scatter",
+           "ENGINES"]
+
+# a values-only repack scatters through int32 flat indices into one
+# device's payload stack, so the stack plus its pad indices must stay
+# below this; a larger plan keeps the host refill
+POS_LIMIT = 2 ** 31
 
 
 # ---------------------------------------------------------------------------
@@ -129,6 +136,43 @@ class DeviceSpGEMMPlan:
     seg_payload_sizes: Tuple[int, ...] = (0,)        # payload tiles per segment
     seg_prod_off: Tuple[int, ...] = (0,)             # flat schedule offsets
     seg_prod_len: Tuple[int, ...] = (0,)             # padded products per seg
+    # values-only repack on the device (see slot_map): the ascending flat
+    # positions of A's / B's stored entries in each device's payload stack
+    # and the CSC data index of the entry at each; None where int32
+    # cannot address the stack
+    a_pos: Optional[np.ndarray] = None    # (P, nnz_a_max) i32
+    a_order: Optional[np.ndarray] = None  # (P, nnz_a_max) i32
+    b_pos: Optional[np.ndarray] = None    # (P, nnz_b_max) i32
+    b_order: Optional[np.ndarray] = None  # (P, nnz_b_max) i32
+
+
+def slot_map(parts: List[BlockSparse], stack_shape: Tuple[int, ...]
+             ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """Where each part's stored entries sit in its device's payload stack
+    (``stack_shape[1:]``): ``(pos, order)``, each ``(P, nnz_max)`` int32.
+    ``pos`` holds the flat positions (``from_csc``'s ``entry_pos``) in
+    ascending order, padded to the longest part with distinct ascending
+    out-of-range indices that a scatter drops; ``order`` the index in the
+    part's CSC data of the entry at each position.
+
+    The structure fixes every position, so new values on the same
+    structure refill the stack by one scatter of ``data[order]`` through
+    ``pos``. Ascending indices matter on the TPU: unsorted ones put a sort
+    of the map into the scatter's program, and about 20 s into its
+    compile. Returns None where the stack and its pads exceed
+    :data:`POS_LIMIT`."""
+    size = int(np.prod(stack_shape[1:]))
+    counts = [len(p.entry_pos) for p in parts]
+    width = max(max(counts, default=0), 1)
+    if size + width > POS_LIMIT:
+        return None
+    pos = np.tile(size + np.arange(width, dtype=np.int64), (len(parts), 1))
+    order = np.tile(np.arange(width, dtype=np.int64), (len(parts), 1))
+    for j, p in enumerate(parts):
+        o = np.argsort(p.entry_pos, kind="stable")
+        pos[j, :counts[j]] = p.entry_pos[o]
+        order[j, :counts[j]] = o
+    return pos.astype(np.int32), order.astype(np.int32)
 
 
 def payload_need_maps(a_parts: List[BlockSparse],
@@ -454,6 +498,8 @@ def build_device_plan(a: CSC, b: CSC, nparts: int,
     tile_bytes = bs * bs * np.dtype(dtype).itemsize
     padded_tiles = Pn * S_total
     nprod_total = int(sum(len(s["a_slot"]) for s in scheds))
+    a_pos, a_order = slot_map(a_parts, a_tiles.shape) or (None, None)
+    b_pos, b_order = slot_map(b_parts, b_tiles.shape) or (None, None)
     plan_seconds = time.perf_counter() - t_plan0
     return DeviceSpGEMMPlan(
         nparts=Pn, bs=bs,
@@ -470,6 +516,7 @@ def build_device_plan(a: CSC, b: CSC, nparts: int,
         chunk=chunk, seg_steps=seg_steps,
         seg_payload_sizes=seg_payload_sizes,
         seg_prod_off=seg_prod_off, seg_prod_len=seg_prod_len,
+        a_pos=a_pos, a_order=a_order, b_pos=b_pos, b_order=b_order,
         stats=dict(
             # shared device-engine stats surface (device_common.REQUIRED_STATS)
             comm_bytes_planned=exact_tiles * tile_bytes,
@@ -499,12 +546,11 @@ def _refill_stack(mat: CSC, part: Partition1D, shape, bs: int, dtype,
     return stack
 
 
-def repack_ring_payloads(plan: DeviceSpGEMMPlan,
-                         a: Optional[CSC] = None,
-                         b: Optional[CSC] = None
-                         ) -> Tuple[Optional[np.ndarray],
-                                    Optional[np.ndarray]]:
-    """Fresh payload stacks for *structure-identical* operands.
+def refill_ring_stacks(plan: DeviceSpGEMMPlan,
+                       a: Optional[CSC] = None,
+                       b: Optional[CSC] = None
+                       ) -> Tuple[Optional[np.ndarray], Optional[np.ndarray]]:
+    """Fresh payload stacks for *structure-identical* operands, on the host.
 
     The values-only half of re-planning: blockize the changed operand(s)
     on the plan's (tile-snapped) partitions and refill the static payload
@@ -513,11 +559,11 @@ def repack_ring_payloads(plan: DeviceSpGEMMPlan,
     adjacency across the backward sweep) costs nothing to keep resident.
     Everything structural — schedules, send slots, step geometry, decode
     coordinates — is untouched, so the caller can reuse the plan and its
-    compiled executable (``core.session`` does exactly that on a
-    structure-keyed cache hit whose values changed). Blockization is
-    deterministic given structure (``from_csc`` orders tiles by
-    (col, row)), so feeding these stacks to the cached executable decodes
-    bitwise-identically to a cold re-plan.
+    compiled executable. Blockization is deterministic given structure
+    (``from_csc`` orders tiles by (col, row)), so feeding these stacks to
+    the cached executable decodes bitwise-identically to a cold re-plan.
+    The reference for :class:`RingValueScatter`, which builds the same
+    stacks on the device.
     """
     dtype = plan.a_tiles.dtype
     sr = plan.semiring
@@ -526,6 +572,46 @@ def repack_ring_payloads(plan: DeviceSpGEMMPlan,
     b_tiles = None if b is None else _refill_stack(
         b, plan.part_n, plan.b_tiles.shape, plan.bs, dtype, sr)
     return a_tiles, b_tiles
+
+
+def ring_values(plan: DeviceSpGEMMPlan, side: int, mat: CSC) -> np.ndarray:
+    """``(P, width)`` new values of ``mat`` as side 0 (A) or 1 (B) of the
+    plan, in the order of its slot map: each ring part is a column range,
+    so its values are one contiguous slice of ``data``, taken in the
+    map's order, cast to the payload dtype and padded to the map's width
+    (the scatter drops the pads)."""
+    order = plan.a_order if side == 0 else plan.b_order
+    part = plan.part_k if side == 0 else plan.part_n
+    splits = part.splits.astype(np.int64)
+    lo, hi = mat.indptr[splits[:-1]], mat.indptr[splits[1:]]
+    out = np.full(order.shape, plan.semiring.zero, dtype=plan.a_tiles.dtype)
+    for j in range(len(lo)):
+        n = hi[j] - lo[j]
+        out[j, :n] = mat.data[lo[j]:hi[j]][order[j, :n]]
+    return out
+
+
+def repack_ring_payloads(plan: DeviceSpGEMMPlan,
+                         a: Optional[CSC] = None,
+                         b: Optional[CSC] = None
+                         ) -> Tuple[Optional[np.ndarray],
+                                    Optional[np.ndarray]]:
+    """The host part of a values-only repack: what goes to the device for
+    the changed side(s) of *structure-identical* operands (``None`` for
+    an unchanged side, which costs nothing).
+
+    Where the plan has slot maps, each side's new values per part
+    (:func:`ring_values`, about nnz·4 bytes), which
+    :class:`RingValueScatter` writes into fresh stacks on the device;
+    where it has none (past :data:`POS_LIMIT`), the whole host-refilled
+    stacks (:func:`refill_ring_stacks`), to be put as they are. Either
+    way the schedules and the compiled executable are reused, and the
+    answer is bitwise that of a cold re-plan.
+    """
+    if plan.a_pos is None or plan.b_pos is None:
+        return refill_ring_stacks(plan, a, b)
+    return tuple(None if m is None else ring_values(plan, i, m)
+                 for i, m in enumerate((a, b)))
 
 
 # ---------------------------------------------------------------------------
@@ -695,6 +781,80 @@ def ring_program(plan: DeviceSpGEMMPlan, mesh: Mesh,
         body, mesh=mesh,
         in_specs=(P(axis),) * 7,
         out_specs=P(axis), check_rep=False))
+
+
+def _scatter_program(stack_shape: Tuple[int, ...], width: int, dtype,
+                     zero: float, sharding: NamedSharding):
+    """A compiled ``(pos, vals) -> stack`` over the ring's mesh: each
+    device builds its payload stack fresh from the semiring's additive
+    identity and writes its values through its slot map's ascending
+    positions (pads dropped)."""
+    axis = sharding.spec[0]
+    inner = tuple(int(x) for x in stack_shape[1:])
+    size = int(np.prod(inner))
+
+    def body(pos, vals):
+        flat = jnp.full((size,), zero, dtype=dtype).at[pos[0]].set(
+            vals[0], mode="drop", indices_are_sorted=True,
+            unique_indices=True)
+        return flat.reshape(inner)[None]
+
+    fn = jax.jit(shard_map(body, mesh=sharding.mesh,
+                           in_specs=(P(axis), P(axis)),
+                           out_specs=P(axis), check_rep=False))
+    rows = int(stack_shape[0])
+    return fn.lower(
+        jax.ShapeDtypeStruct((rows, width), np.int32, sharding=sharding),
+        jax.ShapeDtypeStruct((rows, width), dtype, sharding=sharding),
+    ).compile()
+
+
+class RingValueScatter:
+    """A ring plan's values-only repack on the device.
+
+    Holds the plan's slot maps (:func:`slot_map`) on the device and one
+    compiled scatter per payload stack shape; called with the per-part
+    values of :func:`repack_ring_payloads` (in the maps' order), it puts
+    them on the device and scatters them into fresh stacks, bitwise equal
+    to :func:`refill_ring_stacks`'s. Operands must be structure-identical
+    to the plan's and free of duplicate entries, as ingress validation
+    guarantees."""
+
+    def __init__(self, plan: DeviceSpGEMMPlan, sharding: NamedSharding):
+        self.sharding = sharding
+        maps = (plan.a_pos, plan.b_pos)
+        self.maps = tuple(jax.device_put(m, sharding) for m in maps)
+        # A·A on one part has two stacks of one shape: one program
+        shapes = [(plan.a_tiles.shape, plan.a_pos.shape[1]),
+                  (plan.b_tiles.shape, plan.b_pos.shape[1])]
+        programs = {k: _scatter_program(*k, plan.a_tiles.dtype,
+                                        plan.semiring.zero, sharding)
+                    for k in set(shapes)}
+        self.programs = tuple(programs[k] for k in shapes)
+        self.nbytes = sum(m.nbytes for m in maps)
+
+    def __call__(self, vals: Sequence[Optional[np.ndarray]]
+                 ) -> Tuple[Optional[jax.Array], ...]:
+        """Fresh device stacks for the sides whose ``vals`` are given
+        (None elsewhere), ready on return: the values and the scatters'
+        scratch are freed before the product's output is allocated, so
+        they add nothing to the execute's peak memory, and a scatter that
+        fails on the device fails here."""
+        return jax.block_until_ready(tuple(
+            None if v is None else
+            self.programs[i](self.maps[i], jax.device_put(v, self.sharding))
+            for i, v in enumerate(vals)))
+
+
+def compile_ring_scatter(plan: DeviceSpGEMMPlan, sharding: NamedSharding
+                         ) -> Optional[RingValueScatter]:
+    """The plan's :class:`RingValueScatter` over ``sharding`` (the
+    sharding of :func:`compile_ring`'s args), or None where a slot map is
+    out of int32's reach and :func:`repack_ring_payloads` hands over
+    host-refilled stacks instead."""
+    if plan.a_pos is None or plan.b_pos is None:
+        return None
+    return RingValueScatter(plan, sharding)
 
 
 def decode_ring_output(plan: DeviceSpGEMMPlan, out: np.ndarray) -> CSC:

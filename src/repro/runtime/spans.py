@@ -42,11 +42,19 @@ __all__ = ["SPANS", "span"]
 #   spgemm.session.plan       : host planning of a cold key
 #   spgemm.session.compile    : placing the plan and compiling its program
 #                               (cold key); both set-up, read by no metric
-#   spgemm.repack.blockize    : re-blockizing changed operands into fresh
+#   spgemm.repack.blockize    : the host part of a values-only repack
+#                               (repack_*_payloads): on the 1D ring,
+#                               slicing the changed operands' values per
+#                               part and casting them; on the SUMMA
+#                               engines, re-blockizing them into fresh
 #                               payload stacks; tiles (payload tile slots
 #                               refilled) -> repack_ms
-#   spgemm.repack.h2d         : device_put of the fresh stacks; h2d_bytes
-#                               -> repack_ms
+#   spgemm.repack.h2d         : device_put of the values (1D ring) and
+#                               their scatter into fresh stacks, waited
+#                               for, or device_put of the host's stacks
+#                               (SUMMA); h2d_bytes (bytes put),
+#                               entries (values scattered on the device, 0
+#                               where the host refilled) -> repack_ms
 #   spgemm.execute.dispatch   : launching the compiled program
 #                               (asynchronous); read by no metric: its host
 #                               part is what untraced_host_ms leaves out

@@ -26,6 +26,7 @@ import pytest
 from _device_harness import run_subprocess
 
 from repro.core import SpGEMMSession, erdos_renyi, from_coo
+from repro.core.sparse import CSC
 
 
 def _int_matrix(n=50, seed=3):
@@ -112,13 +113,85 @@ def test_one_sided_value_change_repacks_one_side():
     assert s.last_call["cache_hit"] and s.last_call["repacked"]
     assert s.stats["traces"] == traces
     _assert_bitwise(c, _cold_run(a, b2, bs=16))
-    # the partial-repack helper itself: untouched side comes back None
+    # the partial-repack helpers themselves: untouched side comes back
+    # None; the ring hands over the changed side's values alone, and the
+    # host refill (its reference) a whole stack
     from repro.core.spgemm_1d_device import (build_device_plan,
+                                             refill_ring_stacks,
                                              repack_ring_payloads)
     plan = build_device_plan(a, b, 1, bs=16)
     new_a, new_b = repack_ring_payloads(plan, b=b2)
+    assert new_a is None
+    assert np.array_equal(new_b, b2.data[plan.b_order])
+    new_a, new_b = refill_ring_stacks(plan, b=b2)
     assert new_a is None and new_b is not None
     assert new_b.shape == plan.b_tiles.shape
+
+
+def test_repeated_repacks_compile_nothing():
+    """The values-only scatter is compiled with the entry (the cold
+    call): the first, second and third repack of the entry trace and
+    compile nothing."""
+    import jax.monitoring
+
+    a = _int_matrix().astype(np.float32)
+    s = SpGEMMSession()
+    s.matmul(a, a, bs=16)
+    (entry,) = s._cache.values()
+    assert entry.scatter is not None
+    compiles = []
+
+    def listen(event, *args, **kwargs):
+        if event.startswith("/jax/core/compile/"):
+            compiles.append(event)
+
+    served = []
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for shift in (1.0, 2.0, 3.0):
+            a2 = CSC(a.indptr, a.indices, a.data + shift, a.shape)
+            served.append((a2, s.matmul(a2, a2, bs=16)))
+            assert s.last_call["repacked"]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert s.stats["payload_repacks"] == 3
+    for a2, c in served:
+        _assert_bitwise(c, _cold_run(a2, a2, bs=16))
+
+
+def test_scatter_fault_before_the_swap_quarantines(monkeypatch):
+    """A repack that fails after the new values are on the device, before
+    the fresh stacks are swapped in, quarantines the entry: the call is
+    served by the next rung from the new values, and the next call on the
+    key re-plans and serves them cleanly."""
+    from repro.runtime import RetryPolicy
+    from repro.runtime.faults import SimulatedXlaRuntimeError
+
+    a = _int_matrix().astype(np.float32)
+    s = SpGEMMSession(retry_policy=RetryPolicy(max_retries=0, backoff_s=0.0))
+    s.matmul(a, a, bs=16)
+    (entry,) = s._cache.values()
+    put = []
+
+    def failing_program(pos, vals):
+        put.append(vals)
+        raise SimulatedXlaRuntimeError("simulated scatter failure")
+
+    monkeypatch.setattr(entry.scatter, "programs", (failing_program,) * 2)
+    a2 = CSC(a.indptr, a.indices, a.data * 3.0 + 1.0, a.shape)
+    ref = _cold_run(a2, a2, bs=16)
+    _assert_bitwise(s.matmul(a2, a2, bs=16), ref)
+    assert len(put) == 1 and put[0].shape == (1, a.nnz)  # values were put
+    assert s.last_call["degraded"] and s.last_call["engine"] == "jnp"
+    assert s.stats["quarantined"] == 1
+    assert entry.args == [] and entry.scatter is None    # buffers released
+
+    misses = s.stats["plan_cache_misses"]
+    _assert_bitwise(s.matmul(a2, a2, bs=16), ref)
+    assert s.last_call["engine"] == "pallas" and not s.last_call["degraded"]
+    assert not s.last_call["cache_hit"]
+    assert s.stats["plan_cache_misses"] == misses + 1
 
 
 def test_dtype_mismatched_repack_rejected_same_dtype_accepted():

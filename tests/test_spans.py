@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.core import MIN_PLUS, banded_clustered
+from repro.core import MIN_PLUS, SpGEMMSession, banded_clustered
 from repro.runtime.spans import SPANS
 from repro.serve import SpGEMMRequest, SpGEMMService
 
@@ -123,10 +123,12 @@ def test_counters_equal_the_sizes_they_name(served):
     assert by_name["spgemm.session.fingerprint"] == [
         {"hashed_bytes": fp_bytes}] * 2
     assert by_name["spgemm.session.validate"] == [{"nnz": 2 * m.nnz}] * 2
-    # both sides of A·A changed values: both payload stacks were swapped
+    # both sides of A·A changed values: only the values went to the
+    # device, and both were scattered into fresh payload stacks there
     (h2d,) = by_name["spgemm.repack.h2d"]
-    assert h2d["h2d_bytes"] == plan.a_tiles.nbytes + plan.b_tiles.nbytes
-    assert h2d["h2d_bytes"] == entry.args[0].nbytes + entry.args[1].nbytes
+    assert h2d["entries"] == 2 * m.nnz
+    assert h2d["h2d_bytes"] == 2 * m.nnz * m.data.itemsize
+    assert h2d["h2d_bytes"] < plan.a_tiles.nbytes + plan.b_tiles.nbytes
     (blk,) = by_name["spgemm.repack.blockize"]
     assert blk["tiles"] == (plan.a_tiles.size + plan.b_tiles.size) \
         // (BS * BS)
@@ -136,6 +138,30 @@ def test_counters_equal_the_sizes_they_name(served):
         {"d2h_bytes": out_bytes}] * 2
     assert by_name["spgemm.decode.assemble"] == [
         {"nnz": r0.value.nnz}, {"nnz": r1.value.nnz}]
+
+
+@pytest.mark.parametrize("algorithm,geom", [
+    ("1d", dict(nparts=1)), ("2d", dict(grid=1)),
+    ("3d", dict(grid=1, layers=1))])
+def test_repack_engagement_counter(tmp_path, algorithm, geom):
+    """``entries`` on ``spgemm.repack.h2d`` counts the values scattered on
+    the device: the changed side's nnz on the 1D ring, with ``h2d_bytes``
+    the value bytes; 0 on the SUMMA engines, which put whole stacks."""
+    a, b = _matrix(seed=4), _matrix(seed=5)
+    b2 = _matrix(seed=5, shift=0.5)
+    s = SpGEMMSession()
+    kw = dict(algorithm=algorithm, semiring=MIN_PLUS, bs=BS, **geom)
+    s.matmul(a, b, **kw)
+    _record(tmp_path, lambda: s.matmul(a, b2, **kw))
+    assert s.last_call["repacked"] and s.last_call["algorithm"] == algorithm
+    (h2d,) = [c for n, _, _, c in _host_spans(tmp_path)
+              if n == "spgemm.repack.h2d"]
+    (entry,) = s._cache.values()
+    if algorithm == "1d":
+        assert h2d == {"entries": b2.nnz,
+                       "h2d_bytes": b2.nnz * b2.data.itemsize}
+    else:
+        assert h2d == {"entries": 0, "h2d_bytes": entry.args[1].nbytes}
 
 
 def test_answers_are_the_same_without_the_profiler(served):

@@ -11,7 +11,9 @@ time:
     schedules 367,218 tile products), and the one-launch form of a long
     schedule, which overflows SMEM;
   * the 1D ring's jitted shard_map body on a mesh of 4 described chips,
-    given shapes only (``ring_program`` places nothing).
+    given shapes only (``ring_program`` places nothing);
+  * the values-only repack's scatter into a fresh payload stack at the
+    road-network cell's size, on 1 and 4 described chips.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and pytest-xdist workers import every
@@ -151,6 +153,23 @@ def test_served_ring_names_its_kernel_launches(topo):
            if 'custom_call_target="tpu_custom_call"' in line]
     assert len(ops) == launches
     assert all(re.fullmatch(r"bsr_spgemm_pallas(\.\d+)*", op) for op in ops)
+
+
+@pytest.mark.parametrize("nparts", [1, 4])
+def test_value_scatter_compiles_for_v5e(topo, nparts):
+    """The values-only repack's scatter at the road-network cell's size
+    (8,292 stored tiles, 996k values, min-plus) writes its fresh stack in
+    place: no temporaries beside the stack it returns."""
+    mesh = Mesh(np.array(topo.devices[:nparts]), ("p",))
+    shape = (nparts, 8292 // nparts, BS, BS)
+    width = 996_000 // nparts
+    compiled = spgemm_1d_device._scatter_program(
+        shape, width, np.float32, MIN_PLUS.zero,
+        NamedSharding(mesh, P("p")))
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 4 * int(np.prod(shape[1:]))
+    assert mem.temp_size_in_bytes == 0
+    assert "scatter" in compiled.as_text()
 
 
 def test_session_raises_on_a_program_that_does_not_compile(monkeypatch):
