@@ -33,6 +33,8 @@ import numpy as np
 
 __all__ = ["Semiring", "PLUS_TIMES", "BOOL_OR_AND", "MIN_PLUS", "by_name"]
 
+_MXU_PASSES = {"DEFAULT": 1, "HIGHEST": 6}
+
 
 @dataclasses.dataclass(frozen=True)
 class Semiring:
@@ -59,6 +61,21 @@ class Semiring:
     #   (vals, axis_name) -> vals  — the Split-3D cross-layer merge
     # (psum / pmax / pmin: every registered monoid has a native collective)
     jnp_axis_reduce: Callable = None
+    # the jax.lax.Precision of the MXU dot in jnp_matmul and
+    # jnp_tile_combine (the factories pass this same value to the dot);
+    # None for a combine with no dot
+    dot_precision: object = None
+
+    @property
+    def mxu_passes(self) -> int:
+        """bfloat16 MXU passes per float32 tile product, from
+        ``dot_precision``: 0 without a dot, 1 at DEFAULT, 6 at HIGHEST.
+        The 6 is assumed, XLA's count for a float32 dot at HIGHEST; how
+        many passes Mosaic's ``contract_precision<fp32>`` makes was not
+        measured. Recorded on the dispatch span (``mxu_passes``)."""
+        if self.dot_precision is None:
+            return 0
+        return _MXU_PASSES[self.dot_precision.name]
 
     def prune_mask(self, vals: np.ndarray, tol: float = 0.0) -> np.ndarray:
         """Entries considered nonzero by this semiring: |v - 0̄| > tol for
@@ -79,20 +96,26 @@ def _make_plus_times() -> Semiring:
     import jax
     import jax.numpy as jnp
 
+    # float32 to float32's rounding: a dot with no precision is one
+    # bfloat16 pass on the TPU (Pallas gives it Mosaic's default), HIGHEST
+    # lowers to contract_precision<fp32>
+    precision = jax.lax.Precision.HIGHEST
+
     return Semiring(
         name="plus_times",
         mul=np.multiply,
         add_reduceat=lambda v, s: np.add.reduceat(v, s),
         zero=0.0,
         jnp_matmul=lambda a, b: jnp.matmul(
-            a, b, preferred_element_type=jnp.float32),
+            a, b, precision=precision, preferred_element_type=jnp.float32),
         jnp_add=lambda acc, c: acc + c,
         # the one true MXU fast path: a single f32-accumulating dot
         jnp_tile_combine=lambda acc, a, b: acc + jnp.dot(
-            a, b, preferred_element_type=jnp.float32),
+            a, b, precision=precision, preferred_element_type=jnp.float32),
         jnp_segment_reduce=lambda v, seg, n: jax.ops.segment_sum(
             v, seg, num_segments=n),
         jnp_axis_reduce=lambda v, axis: jax.lax.psum(v, axis),
+        dot_precision=precision,
     )
 
 
@@ -100,11 +123,14 @@ def _make_bool_or_and() -> Semiring:
     import jax
     import jax.numpy as jnp
 
+    # 0/1 operands are exact in bfloat16: one pass suffices
+    precision = jax.lax.Precision.DEFAULT
+
     # represent booleans as {0.0, 1.0}; or == max, and == min(prod on 0/1)
     def _bool_matmul(a, b):
         return jnp.clip(
             jnp.matmul((a != 0).astype(jnp.float32),
-                       (b != 0).astype(jnp.float32),
+                       (b != 0).astype(jnp.float32), precision=precision,
                        preferred_element_type=jnp.float32), 0.0, 1.0)
 
     return Semiring(
@@ -119,6 +145,7 @@ def _make_bool_or_and() -> Semiring:
         jnp_segment_reduce=lambda v, seg, n: jax.ops.segment_max(
             v, seg, num_segments=n),
         jnp_axis_reduce=lambda v, axis: jax.lax.pmax(v, axis),
+        dot_precision=precision,
     )
 
 

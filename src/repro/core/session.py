@@ -658,7 +658,8 @@ class SpGEMMSession:
                                owner=tenant, scatter=scatter)
 
             def do_execute():
-                with span("spgemm.execute.dispatch"):
+                with span("spgemm.execute.dispatch",
+                          mxu_passes=semiring.mxu_passes):
                     res = entry.fn(*entry.args)
                 with span("spgemm.execute.fetch", d2h_bytes=res.nbytes):
                     out = np.asarray(res)

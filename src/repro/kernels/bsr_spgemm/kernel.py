@@ -11,8 +11,9 @@ makes every unit of work a dense ``bs×bs`` semiring tile-product:
 The kernel is **semiring-generic** (ROADMAP "semiring contract"): the
 accumulator resets to ``semiring.zero`` (the additive identity — not a
 literal 0.0, which is the wrong annihilator for min-plus), and each step
-applies ``semiring.jnp_tile_combine``. For plus-times that combine is
-exactly the previous hard-coded MXU path (one f32-accumulating ``jnp.dot``);
+applies ``semiring.jnp_tile_combine``. For plus-times that combine is one
+f32-accumulating ``jnp.dot`` at ``Precision.HIGHEST`` (float32 operands in
+several bfloat16 MXU passes, six assumed, not Mosaic's default single pass);
 bool or-and stays on the MXU (booleanize → dot → clip → max); min-plus runs
 ``bs`` unrolled rank-1 ``min(acc, col + row)`` VPU updates over static
 slices, so no O(bs³) intermediate is materialized.

@@ -56,8 +56,12 @@ __all__ = ["SPANS", "span"]
 #                               entries (values scattered on the device, 0
 #                               where the host refilled) -> repack_ms
 #   spgemm.execute.dispatch   : launching the compiled program
-#                               (asynchronous); read by no metric: its host
-#                               part is what untraced_host_ms leaves out
+#                               (asynchronous); mxu_passes (bfloat16 MXU
+#                               passes per tile product, from the combine's
+#                               dot precision: 6 plus-times, an assumed
+#                               count, 1 boolean, 0 min-plus); read by no
+#                               metric: its host part is what
+#                               untraced_host_ms leaves out
 #   spgemm.execute.fetch      : np.asarray of the output: waits for the
 #                               device, then copies to the host; d2h_bytes
 #                               -> d2h_ms (less the device's busy time)

@@ -75,24 +75,64 @@ def test_repeat_multiply_skips_planning_and_retrace():
     _assert_bitwise(c1, _cold_run(a, a, bs=16))
 
 
-def test_values_only_change_repacks_without_replanning():
+def _published_degree_matrix():
+    """HV15R's 140 entries per column (here 140.4: n=1024, band 1024, 207
+    draws) with float32 normal values, for tiles at the MXU's width."""
+    from repro.core import banded_clustered
+
+    a = banded_clustered(1024, 1024, 207, seed=0)
+    assert abs(a.nnz / a.shape[1] - 140.33) < 0.01 * 140.33
+    return CSC(a.indptr, a.indices,
+               np.random.default_rng(1).standard_normal(a.nnz)
+               .astype(np.float32), a.shape)
+
+
+def _value_gap(a, c):
+    """The widest ``|c - c_ref| / (|A|·|A|)_ij`` of ``c`` against the
+    float64 product ``a·a``, over A·A's symbolic pattern; and the count of
+    entries of ``c`` outside that pattern."""
+    import scipy.sparse as sp
+
+    def dense(m, data):
+        return sp.csc_matrix((data, m.indices, m.indptr),
+                             shape=m.shape).toarray()
+
+    a64 = dense(a, a.data.astype(np.float64))
+    ref, scale = a64 @ a64, np.abs(a64) @ np.abs(a64)
+    got = dense(c, c.data.astype(np.float64))
+    pattern = dense(a, np.ones(a.nnz)) @ dense(a, np.ones(a.nnz)) > 0
+    gap = np.abs(got - ref)[pattern] / scale[pattern]
+    return gap.max(), np.count_nonzero(got[~pattern])
+
+
+@pytest.mark.parametrize("operand, bs", [
+    (_int_matrix, 16), (_published_degree_matrix, 128)],
+    ids=["integer", "published_degree"])
+def test_values_only_change_repacks_without_replanning(operand, bs):
     """Same structure, new values: cache hit + payload repack, no retrace,
-    and the decode matches a cold plan built on the new values bitwise."""
-    a = _int_matrix()
+    and the decode matches a cold plan built on the new values bitwise.
+    With float32 normal values at HV15R's degree, the cold call and the
+    repack both come within float32 rounding of the float64 product (gap
+    at most 1e-6), with no entry outside its pattern."""
+    a = operand()
     s = SpGEMMSession()
-    s.matmul(a, a, bs=16)
+    c1 = s.matmul(a, a, bs=bs)
     traces = s.stats["traces"]
 
     a2 = a.astype(np.float32)       # payload dtype: repack stays legal
     a2.data[:] = a.data * 3.0 + 1.0            # same structure, new values
-    c = s.matmul(a2, a2, bs=16)
+    c = s.matmul(a2, a2, bs=bs)
     assert s.last_call["cache_hit"] and s.last_call["repacked"]
     assert s.stats["payload_repacks"] == 1
     assert s.stats["traces"] == traces
-    _assert_bitwise(c, _cold_run(a2, a2, bs=16))
+    _assert_bitwise(c, _cold_run(a2, a2, bs=bs))
+    if operand is _published_degree_matrix:
+        for m, got in ((a, c1), (a2, c)):
+            gap, extra = _value_gap(m, got)
+            assert gap <= 1e-6 and extra == 0, (gap, extra)
 
     # bit-identical values again: the repack itself is skipped
-    s.matmul(a2, a2, bs=16)
+    s.matmul(a2, a2, bs=bs)
     assert s.last_call["cache_hit"] and not s.last_call["repacked"]
     assert s.stats["payload_repacks"] == 1
 

@@ -5,7 +5,8 @@ A cold call and then a values-only call go through
 tiny structure). The recorded ``.xplane.pb`` must hold every span of
 ``repro.runtime.spans.SPANS`` on the host plane, flat on the warm path,
 with counters that equal the sizes they name; the answers are the same
-with and without the profiler attached.
+with and without the profiler attached. The dispatch span carries the
+semiring's MXU passes per tile product.
 """
 
 import ast
@@ -16,7 +17,8 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.core import MIN_PLUS, SpGEMMSession, banded_clustered
+from repro.core import (BOOL_OR_AND, MIN_PLUS, PLUS_TIMES, SpGEMMSession,
+                        banded_clustered)
 from repro.runtime.spans import SPANS
 from repro.serve import SpGEMMRequest, SpGEMMService
 
@@ -162,6 +164,21 @@ def test_repack_engagement_counter(tmp_path, algorithm, geom):
                        "h2d_bytes": b2.nnz * b2.data.itemsize}
     else:
         assert h2d == {"entries": 0, "h2d_bytes": entry.args[1].nbytes}
+
+
+@pytest.mark.parametrize("semiring, passes", [
+    (PLUS_TIMES, 6), (BOOL_OR_AND, 1), (MIN_PLUS, 0)],
+    ids=["plus_times", "bool_or_and", "min_plus"])
+def test_dispatch_records_the_combines_mxu_passes(tmp_path, semiring,
+                                                  passes):
+    """``mxu_passes`` on ``spgemm.execute.dispatch``: the bfloat16 MXU
+    passes of one float32 tile product, 6 for plus-times at HIGHEST, 1 for
+    the boolean combine, 0 for min-plus, which has no dot."""
+    a = _matrix()
+    s = SpGEMMSession()
+    _record(tmp_path, lambda: s.matmul(a, a, semiring=semiring, bs=BS))
+    assert [c for n, _, _, c in _host_spans(tmp_path)
+            if n == "spgemm.execute.dispatch"] == [{"mxu_passes": passes}]
 
 
 def test_answers_are_the_same_without_the_profiler(served):

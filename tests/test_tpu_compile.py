@@ -6,7 +6,8 @@ memory (SMEM) overflow of the prefetched schedule, primitives the Pallas TPU
 lowering lacks, collectives on a 4-chip mesh — it refuses here, at no chip
 time:
 
-  * ``bsr_spgemm_pallas`` at ``bs=128`` for each semiring;
+  * ``bsr_spgemm_pallas`` at ``bs=128`` for each semiring, and the
+    precision each semiring's tile product lowers at;
   * a schedule far longer than one launch window (the real hv15r-like A²
     schedules 367,218 tile products), and the one-launch form of a long
     schedule, which overflows SMEM;
@@ -21,6 +22,7 @@ test file. The last test runs on the CPU: a session that cannot compile its
 program raises a typed error instead of serving the call on a lower rung.
 """
 
+import base64
 import os
 import re
 
@@ -92,6 +94,34 @@ def test_kernel_compiles_for_v5e(one_chip, semiring):
     compiled = _compile_kernel(one_chip, nprod=4096, na=512, nc=513,
                                semiring=semiring)
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def _mosaic_bodies(lowered_text):
+    """The serialized Mosaic modules of a lowered program's kernels. Their
+    attributes (``#tpu....<...>``) are kept as text in the bytecode."""
+    return [base64.b64decode(b) for b in re.findall(
+        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)]
+
+
+@pytest.mark.parametrize("semiring, matmuls, precision", [
+    (PLUS_TIMES, 1, [b"#tpu.contract_precision<fp32>"]),
+    (BOOL_OR_AND, 1, []),
+    (MIN_PLUS, 0, [])], ids=["plus_times", "bool_or_and", "min_plus"])
+def test_kernel_combine_precision_for_v5e(one_chip, semiring, matmuls,
+                                          precision):
+    """The plus-times tile product lowers as a float32 contraction (six
+    bfloat16 passes), not Mosaic's default single pass; the boolean combine
+    keeps its one exact pass, and min-plus has no MXU op at all."""
+    def run(a, b, *sched):
+        return bsr_spgemm_pallas(a, b, *sched, nprod=64, nc=9, bs=BS,
+                                 interpret=False, semiring=semiring)
+
+    sds = jax.ShapeDtypeStruct
+    args = [sds((8, BS, BS), jnp.float32, sharding=one_chip)] * 2 \
+        + [sds((64,), jnp.int32, sharding=one_chip)] * 4
+    (body,) = _mosaic_bodies(jax.jit(run).lower(*args).as_text())
+    assert body.count(b"tpu.matmul") == matmuls
+    assert re.findall(rb"#tpu\.contract_precision<\w+>", body) == precision
 
 
 def test_long_schedule_compiles_in_windows(one_chip):
