@@ -21,7 +21,8 @@ algorithm-specific parts (who owns what, which collectives move it):
     or its segment-reduce reference (:func:`run_schedule`);
   * mesh construction over the host's visible devices
     (:func:`device_grid_mesh`);
-  * the batched semiring-aware output decode (:func:`decode_tiles`);
+  * the batched semiring-aware output decode (:func:`decode_tiles`), and
+    its gather through a cached structural map (:class:`DecodeMap`);
   * the **shared stats surface**: every device plan's ``stats`` dict carries
     at least :data:`REQUIRED_STATS` — exact planned vs padded communication
     bytes, message count, dense MXU flops and planner wall time — so the
@@ -34,6 +35,7 @@ traced inside the engines' shard_map bodies.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -48,7 +50,7 @@ __all__ = [
     "ENGINES", "REQUIRED_STATS", "CHUNK_STATS", "SESSION_STATS",
     "snap_to_tiles", "blockize_parts", "resolve_engine",
     "check_plan_semiring", "pack_schedules", "run_schedule",
-    "device_grid_mesh", "decode_tiles",
+    "device_grid_mesh", "DecodeMap", "decode_tiles",
 ]
 
 ENGINES = ("pallas", "jnp")
@@ -253,12 +255,68 @@ def device_grid_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     return Mesh(np.array(devs[:need]).reshape(shape), axes)
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class DecodeMap:
+    """C's structural pattern in CSC order, and where each of its entries
+    sits in the fetched ``(D, nc_max, bs, bs)`` output stack.
+
+    The pattern is every position that A's and B's stored patterns can
+    reach; nothing outside it can hold anything but the semiring's
+    identity on finite inputs, so a decode gathers these positions,
+    drops the ones equal to the identity, and needs no scan of the stack
+    and no sort. ``indptr``/``indices`` are read-only: every answer
+    decoded through the map where nothing is pruned shares them.
+
+    ``tiles`` are the tile arguments of :func:`decode_tiles` it was built
+    for (``c_rows``, ``c_cols``, ``c_counts``, ``col_off``, ``col_lim``);
+    a decode with any other takes the scan.
+    """
+
+    indptr: np.ndarray              # (ncols+1,) int64
+    indices: np.ndarray             # (S,) int64 row ids, sorted per column
+    pos: np.ndarray                 # (S,) flat stack positions, intp
+    stack_shape: Tuple[int, ...]
+    out_shape: Tuple[int, int]
+    tiles: Tuple[np.ndarray, ...]
+
+    def serves(self, stack_shape: Tuple[int, ...], out_shape: Tuple[int, int],
+               tiles: Sequence[np.ndarray]) -> bool:
+        """Whether a decode of these arguments may gather through the map."""
+        return (tuple(stack_shape) == self.stack_shape
+                and tuple(out_shape) == self.out_shape
+                and all(np.array_equal(x, y)
+                        for x, y in zip(tiles, self.tiles)))
+
+
+def _decode_mapped(out: np.ndarray, dmap: DecodeMap,
+                   semiring: Semiring) -> CSC:
+    """:func:`decode_tiles` through a structural map: one gather of the
+    structural entries, the identity test on them, and, where some are
+    the identity, a compaction whose column pointers drop the count of
+    pruned entries before each of the map's column boundaries."""
+    with span("spgemm.decode.prune", mapped=1) as s:
+        vals = out.reshape(-1).take(dmap.pos)
+        keep = semiring.prune_mask(vals)
+        pruned = np.flatnonzero(~keep)
+        s.set_metadata(pruned=len(pruned))
+    with span("spgemm.decode.assemble", nnz=len(vals) - len(pruned)):
+        if not len(pruned):
+            return CSC(dmap.indptr, dmap.indices, vals, dmap.out_shape)
+        return CSC(dmap.indptr - np.searchsorted(pruned, dmap.indptr),
+                   dmap.indices[keep], vals[keep], dmap.out_shape)
+
+
 def decode_tiles(out: np.ndarray, c_rows: np.ndarray, c_cols: np.ndarray,
                  c_counts: np.ndarray, semiring: Semiring,
                  out_shape: Tuple[int, int],
                  col_off: Optional[np.ndarray] = None,
-                 col_lim: Optional[np.ndarray] = None) -> CSC:
+                 col_lim: Optional[np.ndarray] = None, *,
+                 decode_map: Optional[DecodeMap] = None) -> CSC:
     """Decode per-device output tile stacks into one global CSC.
+
+    Through ``decode_map`` where it was built for these tile arguments:
+    a gather of C's structural entries (:class:`DecodeMap`). Otherwise
+    the scan below, the map's bitwise reference.
 
     One batched prune-mask scan over every device's stack. Tiles past each
     device's real count are reset to the additive identity first: the
@@ -275,13 +333,19 @@ def decode_tiles(out: np.ndarray, c_rows: np.ndarray, c_cols: np.ndarray,
     col_off  : (D,) element-column offset added per device (1D ring parts)
     col_lim  : (D,) exclusive global column bound per device (defaults to
                the matrix width; the 1D ring passes its part boundaries)
+    decode_map : the structural map of the plan (1D ring, from its second
+               use on), or None
     """
     D, nc_max, bs, _ = out.shape
     if col_off is None:
         col_off = np.zeros(D, dtype=np.int64)
     if col_lim is None:
         col_lim = np.full(D, out_shape[1], dtype=np.int64)
-    with span("spgemm.decode.prune"):
+    if decode_map is not None and decode_map.serves(
+            out.shape, out_shape, (c_rows, c_cols, c_counts, col_off,
+                                   col_lim)):
+        return _decode_mapped(out, decode_map, semiring)
+    with span("spgemm.decode.prune", mapped=0):
         valid_tile = (np.arange(nc_max)[None, :]
                       < np.asarray(c_counts)[:, None])
         out = np.where(valid_tile[:, :, None, None], out,

@@ -31,6 +31,12 @@ with three outcomes:
     entry); the SUMMA engines re-blockize on the host
     (``repack_summa_payloads``). Still zero planning and zero retrace.
 
+On a 1D ring plan's second use (either kind of hit) the session also
+builds the plan's structural decode map (``ring_decode_map``), while the
+device runs the product; every later decode of the plan gathers C's
+entries through it instead of scanning the output stack. A structure
+served once pays nothing for it.
+
 Any structure change, semiring change, engine change or geometry change is
 simply a different key — invalidation is by construction, not by mutation
 tracking. Retrace-freedom is *observable*: the engines' ``trace_probe``
@@ -181,20 +187,27 @@ class _Entry:
     the values ``repack`` hands over into fresh stacks; None for the
     SUMMA engines and for ring plans out of int32's reach, whose
     ``repack`` hands over whole host-refilled stacks.
+
+    ``structure`` builds the plan's structural decode map from the call's
+    operands (:func:`~repro.core.spgemm_1d_device.ring_decode_map`, kept
+    as ``plan.decode_map``, host memory); None for the SUMMA engines,
+    which always scan.
     """
 
     __slots__ = ("plan", "fn", "args", "decode", "repack", "scatter",
-                 "val_fp", "owner", "nbytes")
+                 "structure", "val_fp", "owner", "nbytes")
 
     def __init__(self, plan, fn, args: List, decode: Callable,
                  repack: Callable, val_fp: Tuple[bytes, bytes],
-                 owner: Optional[str] = None, scatter=None):
+                 owner: Optional[str] = None, scatter=None,
+                 structure: Optional[Callable] = None):
         self.plan = plan
         self.fn = fn
         self.args = args
         self.decode = decode
         self.repack = repack
         self.scatter = scatter
+        self.structure = structure
         self.val_fp = val_fp
         self.owner = owner
         self.nbytes = sum(int(getattr(x, "nbytes", 0)) for x in args) \
@@ -202,13 +215,17 @@ class _Entry:
 
     def release(self) -> None:
         """Drop the device buffer references (the payload/schedule stacks in
-        ``args``, the scatter's slot maps) and the compiled executables so
-        eviction actually returns device memory — an evicted entry kept
-        alive by a stray reference must not pin its arrays."""
+        ``args``, the scatter's slot maps), the compiled executables and
+        the plan's decode map so eviction actually returns the memory —
+        an evicted entry kept alive by a stray reference must not pin its
+        arrays."""
         self.args = []
         self.fn = None
         self.repack = None
         self.scatter = None
+        if self.structure is not None:
+            self.plan.decode_map = None
+        self.structure = None
 
 
 class SpGEMMSession:
@@ -390,9 +407,9 @@ class SpGEMMSession:
               layers: int, bs: int, nblocks: Optional[int],
               semiring: Semiring, dtype, chunk: Optional[int]):
         """Host planning only (the ``plan`` stage); returns
-        (plan, decode, repack)."""
+        (plan, decode, repack, structure)."""
         from .spgemm_1d_device import (build_device_plan, decode_ring_output,
-                                       repack_ring_payloads)
+                                       repack_ring_payloads, ring_decode_map)
         from .spgemm_2d_device import (build_summa_plan, decode_summa_output,
                                        repack_summa_payloads)
 
@@ -402,11 +419,12 @@ class SpGEMMSession:
                     a, b, nparts, bs=bs, nblocks=nblocks, dtype=dtype,
                     semiring=semiring,
                     a_blockize_cache=self._blockize_cache, chunk=chunk)
-                return plan, decode_ring_output, repack_ring_payloads
+                return (plan, decode_ring_output, repack_ring_payloads,
+                        ring_decode_map)
             plan = build_summa_plan(
                 a, b, grid=grid, layers=layers if algorithm == "3d" else 1,
                 bs=bs, dtype=dtype, semiring=semiring)
-            return plan, decode_summa_output, repack_summa_payloads
+            return plan, decode_summa_output, repack_summa_payloads, None
 
     def _compile(self, plan, algorithm: str, engine: str):
         """Place the plan and trace + lower + compile the shard_map body
@@ -644,7 +662,7 @@ class SpGEMMSession:
                     repacked = True
             else:
                 t0 = time.perf_counter()
-                plan, decode, repack = self._stage(
+                plan, decode, repack, structure = self._stage(
                     "plan",
                     lambda: self._plan(a, b, algorithm, geom[0], grid,
                                        layers, bs, nblocks, semiring,
@@ -655,12 +673,19 @@ class SpGEMMSession:
                     lambda: self._compile(plan, algorithm, engine), ctx)
                 plan_seconds = time.perf_counter() - t0
                 entry = _Entry(plan, fn, args, decode, repack, val_fp,
-                               owner=tenant, scatter=scatter)
+                               owner=tenant, scatter=scatter,
+                               structure=structure)
 
             def do_execute():
                 with span("spgemm.execute.dispatch",
                           mxu_passes=semiring.mxu_passes):
                     res = entry.fn(*entry.args)
+                if hit and entry.structure is not None \
+                        and entry.plan.decode_map is None:
+                    # the plan's second use: its structure repeats, so
+                    # build the decode map, while the device runs
+                    entry.plan.decode_map = entry.structure(entry.plan,
+                                                            a, b)
                 with span("spgemm.execute.fetch", d2h_bytes=res.nbytes):
                     out = np.asarray(res)
                 return entry.decode(entry.plan, out)

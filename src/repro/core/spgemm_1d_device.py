@@ -63,10 +63,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compat import cpu_device_mesh, shard_map
+from ..runtime.spans import span
 from .blocksparse import BlockSparse, build_schedule, flags_from_c_slot
-from .device_common import (ENGINES, blockize_parts, check_plan_semiring,
-                            decode_tiles, pack_schedules, resolve_engine,
-                            run_schedule, snap_to_tiles)
+from .device_common import (ENGINES, DecodeMap, blockize_parts,
+                            check_plan_semiring, decode_tiles,
+                            pack_schedules, resolve_engine, run_schedule,
+                            snap_to_tiles)
 from .plan import BYTES_PER_NNZ, Partition1D
 from .semiring import PLUS_TIMES, Semiring
 from .sparse import CSC
@@ -76,7 +78,7 @@ __all__ = ["DeviceSpGEMMPlan", "build_device_plan", "compile_ring",
            "decode_ring_output", "payload_need_maps", "repack_ring_payloads",
            "segment_ring_schedule", "slot_map", "refill_ring_stacks",
            "ring_values", "RingValueScatter", "compile_ring_scatter",
-           "ENGINES"]
+           "ring_decode_map", "ENGINES"]
 
 # a values-only repack scatters through int32 flat indices into one
 # device's payload stack, so the stack plus its pad indices must stay
@@ -144,6 +146,9 @@ class DeviceSpGEMMPlan:
     a_order: Optional[np.ndarray] = None  # (P, nnz_a_max) i32
     b_pos: Optional[np.ndarray] = None    # (P, nnz_b_max) i32
     b_order: Optional[np.ndarray] = None  # (P, nnz_b_max) i32
+    # the structural decode map (see ring_decode_map), host memory; set
+    # by the session on the plan's second use, None until then
+    decode_map: Optional[DecodeMap] = None
 
 
 def slot_map(parts: List[BlockSparse], stack_shape: Tuple[int, ...]
@@ -857,18 +862,85 @@ def compile_ring_scatter(plan: DeviceSpGEMMPlan, sharding: NamedSharding
     return RingValueScatter(plan, sharding)
 
 
+def ring_decode_map(plan: DeviceSpGEMMPlan, a: CSC, b: CSC) -> DecodeMap:
+    """The plan's :class:`~repro.core.device_common.DecodeMap`, from its
+    operands ``a`` and ``b`` (any values; only their structure is read).
+
+    C's structural pattern is the symbolic product of A's and B's stored
+    patterns (scipy, all-one data, rows sorted within each column). Entry
+    ``(r, c)`` lies in part ``d`` of ``part_n`` at local column ``l = c -
+    splits[d]``, in the output tile slot ``t`` of tile ``(r // bs, l //
+    bs)`` among the part's ``c_rows``/``c_cols``, at flat position
+    ``((d·nc_max + t)·bs + r mod bs)·bs + l mod bs`` of the fetched
+    stack: the inverse of the scan decode's coordinates. Raises where a
+    structural tile is none of the plan's output tiles."""
+    import scipy.sparse as sp
+
+    D, nc_max, bs = plan.nparts, plan.nc_max, plan.bs
+    m, n = plan.out_shape
+    splits = plan.part_n.splits.astype(np.int64)
+    with span("spgemm.decode.structure") as s:
+        pa, pb = (sp.csc_array((np.ones(x.nnz, np.float32), x.indices,
+                                x.indptr), shape=x.shape).tocsr()
+                  for x in (a, b))
+        # the product in CSR, then to CSC: the conversion sorts each
+        # column's rows
+        pc = (pa @ pb).tocsc()
+        indptr = pc.indptr.astype(np.int64, copy=False)
+        indices = pc.indices.astype(np.int64, copy=False)
+        del pa, pb, pc
+        counts = np.diff(indptr)
+        # per column: its part, and its local tile column and offset in it
+        col_part = np.searchsorted(splits[1:], np.arange(n), side="right")
+        ltile, lin = np.divmod(np.arange(n) - splits[col_part], bs)
+        rtile, rin = np.divmod(indices, bs)
+        # the plan's output tiles, keyed (part, tile row, tile col)
+        ntr = max(-(-m // bs), 1)
+        nct = max(-(-int(np.diff(splits).max(initial=0)) // bs), 1)
+        td, tslot = np.nonzero(np.arange(nc_max)[None, :]
+                               < plan.c_counts[:, None])
+        tile_key = ((td * ntr + plan.c_rows[td, tslot]) * nct
+                    + plan.c_cols[td, tslot])
+        order = np.argsort(tile_key)
+        tile_key, tslot = tile_key[order], tslot[order]
+        key = np.repeat(col_part * ntr * nct + ltile, counts) + rtile * nct
+        at = np.minimum(np.searchsorted(tile_key, key),
+                        max(len(tile_key) - 1, 0))
+        if len(key) and (not len(tile_key)
+                         or not np.array_equal(tile_key[at], key)):
+            raise ValueError("a structural tile of A·B is none of the "
+                             "plan's output tiles")
+        del key, rtile
+        # intp, not int32: numpy's take converts other index types to
+        # intp on every call, a copy as large as the map
+        pos = (np.repeat(col_part * nc_max * bs * bs + lin, counts)
+               + tslot[at] * (bs * bs) + rin * bs).astype(np.intp,
+                                                         copy=False)
+        s.set_metadata(nnz=len(indices))
+    indptr.flags.writeable = False
+    indices.flags.writeable = False
+    return DecodeMap(indptr=indptr, indices=indices, pos=pos,
+                     stack_shape=(D, nc_max, bs, bs), out_shape=(m, n),
+                     tiles=tuple(np.array(x) for x in (
+                         plan.c_rows, plan.c_cols, plan.c_counts,
+                         splits[:-1], splits[1:])))
+
+
 def decode_ring_output(plan: DeviceSpGEMMPlan, out: np.ndarray) -> CSC:
     """Decode the raw ``(P, nc_max, bs, bs)`` ring output to a global CSC.
 
     The shared semiring-aware decode (``device_common.decode_tiles``): each
     device's output-tile columns are local to its ``part_n`` slice, so the
     part's element offset is added and columns are clipped at the part's
-    upper boundary before the single global COO assembly.
+    upper boundary before the single global COO assembly. Where the plan
+    holds a structural map (:func:`ring_decode_map`), the decode gathers
+    through it instead.
     """
     splits = plan.part_n.splits.astype(np.int64)
     return decode_tiles(out, plan.c_rows, plan.c_cols, plan.c_counts,
                         plan.semiring, plan.out_shape,
-                        col_off=splits[:-1], col_lim=splits[1:])
+                        col_off=splits[:-1], col_lim=splits[1:],
+                        decode_map=plan.decode_map)
 
 
 def run_device_spgemm(plan: DeviceSpGEMMPlan,

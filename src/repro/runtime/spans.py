@@ -13,7 +13,8 @@ Tracing is on exactly when a profiler is attached; with none attached a
 span costs about a microsecond and records nothing. The spans add no
 synchronisation: they time what the host does, and the device trace times
 the device. Counter values are O(1) to compute (sums of ``nbytes``,
-lengths), never a pass over the entries.
+lengths) or come out of the pass the phase makes anyway; no counter adds
+a pass over the entries.
 
 The spans are flat leaves: none encloses another on the served path, and
 nothing brackets a whole call, so each moment of host time is attributed
@@ -65,17 +66,32 @@ __all__ = ["SPANS", "span"]
 #   spgemm.execute.fetch      : np.asarray of the output: waits for the
 #                               device, then copies to the host; d2h_bytes
 #                               -> d2h_ms (less the device's busy time)
-#   spgemm.decode.prune       : resetting unwritten tiles, the prune-mask
-#                               scan, nonzero and the gathers -> decode_ms
-#   spgemm.decode.assemble    : from_coo of the kept entries; nnz (the
-#                               answer's) -> decode_ms
+#   spgemm.decode.structure   : building a 1D ring plan's structural
+#                               decode map on its second use (symbolic
+#                               A·B on the host, each entry's position in
+#                               the output stack); nnz (C's structural
+#                               entries); set-up, read by no metric
+#   spgemm.decode.prune       : through the plan's map, the gather of C's
+#                               structural entries from the stack and the
+#                               identity test; without one, resetting
+#                               unwritten tiles, the prune-mask scan,
+#                               nonzero and the gathers; mapped (1 where
+#                               the map served, 0 where the scan ran),
+#                               pruned (map only: structural entries
+#                               dropped as the identity) -> decode_ms
+#   spgemm.decode.assemble    : through the map, nothing where every
+#                               structural entry is kept, else the
+#                               compaction of the kept entries; without
+#                               one, from_coo's sort of the kept entries;
+#                               nnz (the answer's) -> decode_ms,
+#                               decode_assemble_ms
 # untraced_host_ms reads the host time of a request that no span covers.
 SPANS = ("spgemm.service.coalesce", "spgemm.session.validate",
          "spgemm.session.fingerprint", "spgemm.session.plan",
          "spgemm.session.compile", "spgemm.repack.blockize",
          "spgemm.repack.h2d", "spgemm.execute.dispatch",
          "spgemm.execute.fetch", "spgemm.decode.prune",
-         "spgemm.decode.assemble")
+         "spgemm.decode.assemble", "spgemm.decode.structure")
 
 
 def span(name: str, **counters) -> jax.profiler.TraceAnnotation:

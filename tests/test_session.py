@@ -24,6 +24,7 @@ import textwrap
 import numpy as np
 import pytest
 from _device_harness import run_subprocess
+from _trace import host_spans, record
 
 from repro.core import SpGEMMSession, erdos_renyi, from_coo
 from repro.core.sparse import CSC
@@ -35,6 +36,14 @@ def _int_matrix(n=50, seed=3):
     a = erdos_renyi(n, n, 4.0, seed=seed)
     a.data[:] = np.rint(2 * a.data)
     a.data[a.data == 0] = 1.0
+    return a
+
+
+def _positive_matrix():
+    """A float32 operand with positive integer values: no entry of its
+    product cancels, so every structural entry is in the answer."""
+    a = _int_matrix().astype(np.float32)
+    a.data[:] = np.abs(a.data)
     return a
 
 
@@ -367,6 +376,82 @@ def test_algorithms_share_session_not_entries():
         assert s.last_call["cache_hit"], alg
 
 
+def test_decode_map_is_built_on_the_second_use(tmp_path):
+    """The cold call scans and builds no map; the plan's second use (a
+    hit) builds it once, between its dispatch and its fetch; that call
+    and every later one, repacks included, decode through it, bitwise
+    as the scan decodes."""
+    a = _positive_matrix()
+    a2 = a.astype(np.float32)
+    a2.data[:] = a.data * 2.0 + 1.0
+    s = SpGEMMSession()
+    calls = [a, a, a2, a]
+    got = record(tmp_path, lambda: [s.matmul(m, m, bs=16) for m in calls])
+    spans = host_spans(tmp_path)
+    assert [c["mapped"] for n, _, _, c in spans
+            if n == "spgemm.decode.prune"] == [0, 1, 1, 1]
+    assert [n for n, *_ in spans if n in (
+        "spgemm.execute.dispatch", "spgemm.decode.structure")] == [
+        "spgemm.execute.dispatch", "spgemm.execute.dispatch",
+        "spgemm.decode.structure", "spgemm.execute.dispatch",
+        "spgemm.execute.dispatch"]
+    (entry,) = s._cache.values()
+    dmap = entry.plan.decode_map
+    assert [c for n, _, _, c in spans if n == "spgemm.decode.structure"] \
+        == [{"nnz": len(dmap.indices)}]
+    for m, c in zip(calls, got):
+        _assert_bitwise(c, _cold_run(m, m, bs=16))
+    for c in got[1:]:
+        assert c.indices is dmap.indices and c.indptr is dmap.indptr
+
+
+def test_served_answers_index_arrays_are_read_only():
+    """Answers decoded through the map share its index arrays: they are
+    read-only, and each answer's values are its own."""
+    a = _positive_matrix()
+    s = SpGEMMSession()
+    s.matmul(a, a, bs=16)
+    c1, c2 = s.matmul(a, a, bs=16), s.matmul(a, a, bs=16)
+    for c in (c1, c2):
+        for arr in (c.indices, c.indptr):
+            with pytest.raises(ValueError, match="read-only"):
+                arr[0] = 1
+    c1.data[0] += 1
+    assert c2.data[0] != c1.data[0]
+
+
+@pytest.mark.parametrize("algorithm,geom", [
+    ("2d", dict(grid=1)), ("3d", dict(grid=1, layers=1))])
+def test_summa_engines_build_no_decode_map(tmp_path, algorithm, geom):
+    a = _int_matrix().astype(np.float32)
+    s = SpGEMMSession()
+    record(tmp_path, lambda: [s.matmul(a, a, algorithm=algorithm, bs=16,
+                                       **geom) for _ in range(3)])
+    spans = host_spans(tmp_path)
+    assert [c["mapped"] for n, _, _, c in spans
+            if n == "spgemm.decode.prune"] == [0, 0, 0]
+    assert "spgemm.decode.structure" not in {n for n, *_ in spans}
+
+
+def test_decode_map_is_dropped_on_eviction():
+    m0, m1 = (_int_matrix(seed=i).astype(np.float32) for i in range(2))
+    s = SpGEMMSession(maxsize=1)
+    s.matmul(m0, m0, bs=16)
+    s.matmul(m0, m0, bs=16)
+    (entry,) = s._cache.values()
+    plan = entry.plan
+    assert plan.decode_map is not None
+    s.matmul(m1, m1, bs=16)
+    assert s.stats["evictions"] == 1
+    assert plan.decode_map is None
+    # the evicted structure comes back cold: it scans again, then maps
+    s.matmul(m0, m0, bs=16)
+    (entry,) = s._cache.values()
+    assert entry.plan is not plan and entry.plan.decode_map is None
+    s.matmul(m0, m0, bs=16)
+    assert entry.plan.decode_map is not None
+
+
 def test_lru_eviction_oldest_first():
     mats = [_int_matrix(seed=i) for i in range(3)]
     s = SpGEMMSession(maxsize=2)
@@ -419,6 +504,12 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
         c2 = s.matmul(a, a, nparts=4, bs=8, semiring=sr)
         assert s.last_call["cache_hit"], srname
         assert s.stats["traces"] == traces, srname
+        # the hit built the plan's decode map and decoded through it: the
+        # answer shares its indices unless entries cancelled
+        dmap = next(reversed(s._cache.values())).plan.decode_map
+        assert dmap is not None, srname
+        assert (c2.indices is dmap.indices) == (
+            c2.nnz == len(dmap.indices)), srname
         ref = run_device_spgemm(
             build_device_plan(a, a, 4, bs=8, semiring=sr))
         for x in (c1, c2):
@@ -433,6 +524,8 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
             build_device_plan(a2, a2, 4, bs=8, semiring=sr))
         assert np.array_equal(c3.data, ref3.data), srname
         assert np.array_equal(c3.indices, ref3.indices), srname
+        assert np.array_equal(c3.indptr, ref3.indptr), srname
+        assert c3.data.dtype == ref3.data.dtype, srname
 
     # 2D SUMMA entries on a 2x2 grid through the same session
     c2d = s.matmul(a, a, algorithm="2d", grid=2, bs=8)

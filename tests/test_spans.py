@@ -6,16 +6,17 @@ tiny structure). The recorded ``.xplane.pb`` must hold every span of
 ``repro.runtime.spans.SPANS`` on the host plane, flat on the warm path,
 with counters that equal the sizes they name; the answers are the same
 with and without the profiler attached. The dispatch span carries the
-semiring's MXU passes per tile product.
+semiring's MXU passes per tile product. The second call is the plan's
+second use: it builds the structural decode map and decodes through it.
 """
 
 import ast
 from pathlib import Path
 
-import jax
 import numpy as np
 import pytest
-from jax.profiler import ProfileData
+from _trace import host_spans as _host_spans
+from _trace import record as _record
 
 from repro.core import (BOOL_OR_AND, MIN_PLUS, PLUS_TIMES, SpGEMMSession,
                         banded_clustered)
@@ -34,32 +35,6 @@ def _matrix(n=96, seed=3, shift=0.0):
 
 def _request(a):
     return SpGEMMRequest(tenant="t", a=a, b=a, semiring=MIN_PLUS, bs=BS)
-
-
-def _record(log_dir, fn):
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
-    try:
-        return fn()
-    finally:
-        jax.profiler.stop_trace()
-
-
-def _host_spans(log_dir):
-    """(name, start_ns, end_ns, counters) of every ``spgemm.`` event on
-    the host plane, in start order."""
-    (path,) = sorted(Path(log_dir).rglob("*.xplane.pb"))
-    out = []
-    for plane in ProfileData.from_file(str(path)).planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith("spgemm."):
-                    out.append((e.name, e.start_ns, e.end_ns,
-                                dict(e.stats)))
-    return sorted(out, key=lambda x: x[1])
 
 
 def _same(x, y):
@@ -105,8 +80,8 @@ def test_no_span_encloses_another_on_the_warm_path(served):
         "spgemm.service.coalesce", "spgemm.session.validate",
         "spgemm.session.fingerprint", "spgemm.repack.blockize",
         "spgemm.repack.h2d", "spgemm.execute.dispatch",
-        "spgemm.execute.fetch", "spgemm.decode.prune",
-        "spgemm.decode.assemble"]
+        "spgemm.decode.structure", "spgemm.execute.fetch",
+        "spgemm.decode.prune", "spgemm.decode.assemble"]
     for (_, _, end, _), (_, start, _, _) in zip(warm, warm[1:]):
         assert end <= start
 
@@ -140,6 +115,12 @@ def test_counters_equal_the_sizes_they_name(served):
         {"d2h_bytes": out_bytes}] * 2
     assert by_name["spgemm.decode.assemble"] == [
         {"nnz": r0.value.nnz}, {"nnz": r1.value.nnz}]
+    # the cold call scans; the second builds the map and gathers through
+    # it, and min-plus of finite weights keeps every structural entry
+    assert by_name["spgemm.decode.prune"] == [
+        {"mapped": 0}, {"mapped": 1, "pruned": 0}]
+    assert by_name["spgemm.decode.structure"] == [{"nnz": r1.value.nnz}]
+    assert len(plan.decode_map.indices) == r1.value.nnz
 
 
 @pytest.mark.parametrize("algorithm,geom", [
