@@ -144,7 +144,7 @@ def main(scale: int = 1) -> Csv:
     qstats = qsvc.stats()
     csv.add("quota/evictions", qstats["evictions_by_tenant"].get("carol", 0),
             "tenant_quota=1 over 3 distinct structures")
-    csv.add("quota/entries_cached", qsvc.session.cached_entries("carol"))
+    csv.add("quota/entries_cached", qsvc.cached_entries("carol"))
     return csv
 
 

@@ -9,11 +9,13 @@ schedule join, static-shape packing) and **tracing/compiling** the
 shard_map ring — both of which depend only on the operands' *sparsity
 structure* and the call geometry, never on the numeric values.
 
-:class:`SpGEMMSession` exploits that split. Every multiply is served from
-an LRU cache keyed on
+:class:`SpGEMMSession` exploits that split. A multiply is two steps:
+:meth:`~SpGEMMSession.prepare` validates the operands and fingerprints them
+once into a :class:`Call`; :meth:`~SpGEMMSession.run` serves it from an LRU
+cache keyed (by ``_key`` alone) on
 
-    (algorithm, mesh geometry (nparts / grid×layers), bs, nblocks,
-     semiring, engine, payload dtype,
+    (algorithm, mesh geometry (nparts / grid×layers), bs, nblocks and
+     chunk (1D only), semiring, engine, payload dtype,
      structure fingerprint of A, structure fingerprint of B)
 
 with three outcomes:
@@ -36,6 +38,12 @@ builds the plan's structural decode map (``ring_decode_map``), while the
 device runs the product; every later decode of the plan gathers C's
 entries through it instead of scanning the output stack. A structure
 served once pays nothing for it.
+
+``matmul`` is ``run(prepare(...))``; the serving layer groups prepared
+calls on ``Call.group``. The session is only the cache (``maxsize``,
+``max_bytes``, LRU order): ``last_call`` names the key that served a call
+and what its budgets evicted, and the serving layer keeps tenant
+accounting on top of ``entries`` and ``evict``.
 
 Any structure change, semiring change, engine change or geometry change is
 simply a different key — invalidation is by construction, not by mutation
@@ -67,6 +75,7 @@ that keeps failing. Whatever escapes the ladder is a typed
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import time
 from collections import OrderedDict
@@ -83,9 +92,9 @@ from .validate import (CompileError, DeviceExecError, SpGEMMError,
                        ValidationError, validate_matmul_operands,
                        wrap_stage_error)
 
-__all__ = ["SpGEMMSession", "session_or_new", "as_payload_dtype",
-           "structure_fingerprint", "values_fingerprint",
-           "fingerprint_nbytes", "ALGORITHMS", "DOWNGRADE"]
+__all__ = ["SpGEMMSession", "Call", "session_or_new", "as_payload_dtype",
+           "structure_fingerprint", "values_fingerprint", "ALGORITHMS",
+           "DOWNGRADE"]
 
 ALGORITHMS = ("1d", "2d", "3d")
 
@@ -115,13 +124,6 @@ def values_fingerprint(mat: CSC) -> bytes:
     h = hashlib.blake2b(digest_size=16)
     h.update(mat.data.tobytes())
     return h.digest()
-
-
-def fingerprint_nbytes(*mats: CSC) -> int:
-    """Bytes that the structure and values fingerprints of ``mats`` hash
-    (a span counter: O(1))."""
-    return sum(16 + m.indptr.nbytes + m.indices.nbytes + m.data.nbytes
-               for m in mats)
 
 
 def _stored_entries(*mats) -> int:
@@ -172,15 +174,56 @@ def session_or_new(session: Optional["SpGEMMSession"],
     return session
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class Call:
+    """One prepared multiply (:meth:`SpGEMMSession.prepare`): the validated
+    operands, the normalised knobs, and the operands' structure and values
+    fingerprints, computed once."""
+
+    a: CSC
+    b: CSC
+    algorithm: str
+    nparts: int
+    grid: int
+    layers: int
+    bs: int
+    nblocks: Optional[int]
+    chunk: Optional[int]
+    semiring: Semiring
+    engine: str
+    dtype: np.dtype
+    struct_fp: Tuple[bytes, bytes]
+    val_fp: Tuple[bytes, bytes]
+
+    @property
+    def group(self) -> tuple:
+        """Calls with equal groups are served by one multiply."""
+        return _key(self, self.algorithm, self.engine), self.val_fp
+
+
+def _key(call: Call, algorithm: str, engine: str) -> tuple:
+    """The cache key that serves ``call`` on the rung (algorithm, engine),
+    and the one place that decides which knobs split entries. ``nblocks``
+    and ``chunk`` are 1D-ring knobs the SUMMA planners lack; a downgraded
+    1d rung inherits the 2d/3d call's ``grid*grid`` devices; a 2d rung
+    collapses the layers. ``key[1]`` is the geometry the rung plans."""
+    if algorithm == "1d":
+        geom = (call.nparts if call.algorithm == "1d"
+                else call.grid * call.grid,)
+        ring = (call.nblocks, call.chunk)
+    else:
+        geom = (call.grid, call.layers if algorithm == "3d" else 1)
+        ring = (None, None)
+    return (algorithm, geom, call.bs, *ring, call.semiring.name, engine,
+            call.dtype.str, *call.struct_fp)
+
+
 class _Entry:
     """One cached (plan, executable, device args) triple.
 
-    ``owner`` is the tenant that planned the entry (None outside the
-    serving layer) — budgets charge the creator even when other tenants'
-    structure-identical requests later hit the same entry. ``nbytes`` is
-    the device footprint of the entry's argument stacks, fixed at compile
-    time (values-only repacks swap same-shape payloads in place), plus
-    the slot maps of its device scatter.
+    ``nbytes`` is the device footprint of the entry's argument stacks,
+    fixed at compile time (values-only repacks swap same-shape payloads in
+    place), plus the slot maps of its device scatter.
 
     ``scatter`` is the 1D ring's values-only repack on the device
     (:class:`~repro.core.spgemm_1d_device.RingValueScatter`), which writes
@@ -195,12 +238,11 @@ class _Entry:
     """
 
     __slots__ = ("plan", "fn", "args", "decode", "repack", "scatter",
-                 "structure", "val_fp", "owner", "nbytes")
+                 "structure", "val_fp", "nbytes")
 
     def __init__(self, plan, fn, args: List, decode: Callable,
                  repack: Callable, val_fp: Tuple[bytes, bytes],
-                 owner: Optional[str] = None, scatter=None,
-                 structure: Optional[Callable] = None):
+                 scatter=None, structure: Optional[Callable] = None):
         self.plan = plan
         self.fn = fn
         self.args = args
@@ -209,7 +251,6 @@ class _Entry:
         self.scatter = scatter
         self.structure = structure
         self.val_fp = val_fp
-        self.owner = owner
         self.nbytes = sum(int(getattr(x, "nbytes", 0)) for x in args) \
             + (scatter.nbytes if scatter is not None else 0)
 
@@ -246,6 +287,9 @@ class SpGEMMSession:
                          the executed plan's stats surface
         plan_stats     : the executed plan's whole ``stats`` dict (tile,
                          product and slot counts)
+        key            : the cache key that served the call
+        evicted        : (key, nbytes) of the entries this call's budgets
+                         evicted (a cold call only)
         algorithm      : the algorithm rung that actually served the call
         engine         : the engine rung that actually served the call
         requested_algorithm : what the caller asked for (== algorithm
@@ -265,21 +309,11 @@ class SpGEMMSession:
     ``breaker_threshold`` — consecutive failures of one cache key before
                           its circuit opens and the rung fails fast.
 
-    Serving knobs (the multi-tenant budget surface the serving layer in
-    ``serve/spgemm_service.py`` drives; all default off):
-
-    ``max_bytes``         — global LRU byte budget over cached entries'
-                          device argument stacks (``stats["bytes_cached"]``
-                          is the tracked quantity); oldest entries are
+    ``max_bytes``       — global LRU byte budget (default off) over cached
+                          entries' device argument stacks
+                          (``stats["bytes_cached"]``); oldest entries are
                           evicted until the budget holds, keeping at least
                           the newest so an oversized multiply still serves.
-    ``tenant_quota``      — max cached entries *created by* any one tenant
-                          (``matmul(tenant=...)`` tags entries).
-    ``tenant_max_bytes``  — per-tenant LRU byte budget over the entries a
-                          tenant created.
-    ``on_evict``          — ``hook(owner, key, nbytes)`` fired on every
-                          budget/LRU eviction (not quarantine), so the
-                          serving layer can attribute evictions per tenant.
     """
 
     def __init__(self, maxsize: int = 32,
@@ -290,25 +324,17 @@ class SpGEMMSession:
                  retry_sleep: Callable[[float], None] = time.sleep,
                  retry_rng: Optional[np.random.Generator] = None,
                  breaker_threshold: int = 3,
-                 max_bytes: Optional[int] = None,
-                 tenant_quota: Optional[int] = None,
-                 tenant_max_bytes: Optional[int] = None,
-                 on_evict: Optional[Callable] = None):
+                 max_bytes: Optional[int] = None):
         if maxsize < 1:
             raise ValueError(f"maxsize must be >= 1, got {maxsize}")
         if breaker_threshold < 1:
             raise ValueError(f"breaker_threshold must be >= 1, "
                              f"got {breaker_threshold}")
-        for nm, v in (("max_bytes", max_bytes),
-                      ("tenant_quota", tenant_quota),
-                      ("tenant_max_bytes", tenant_max_bytes)):
-            if v is not None and v < 1:
-                raise ValueError(f"{nm} must be >= 1 or None, got {v}")
+        if max_bytes is not None and max_bytes < 1:
+            raise ValueError(f"max_bytes must be >= 1 or None, "
+                             f"got {max_bytes}")
         self.maxsize = maxsize
         self.max_bytes = max_bytes
-        self.tenant_quota = tenant_quota
-        self.tenant_max_bytes = tenant_max_bytes
-        self.on_evict = on_evict
         self.interpret = interpret
         self.validate = validate
         self.fault_injector = fault_injector
@@ -365,49 +391,24 @@ class SpGEMMSession:
             entry.release()
             self.stats["quarantined"] += 1
 
-    def _evict(self, key: tuple) -> None:
-        """Evict one cached entry: release device buffers, settle the byte
-        ledger, and fire the serving layer's attribution hook."""
-        entry = self._cache.pop(key)
-        self.stats["evictions"] += 1
-        self.stats["bytes_cached"] -= entry.nbytes
-        if self.on_evict is not None:
-            self.on_evict(entry.owner, key, entry.nbytes)
-        entry.release()
+    def _enforce_budgets(self) -> List[Tuple[tuple, int]]:
+        """Evict LRU-first until the entry count, then the byte budget,
+        holds; returns the evicted (key, nbytes). The byte budget always
+        keeps the newest entry — a single multiply larger than the budget
+        still serves (and is evicted by whatever lands next), it just
+        can't pin neighbours."""
+        evicted = []
+        while len(self._cache) > self.maxsize or (
+                self.max_bytes is not None and len(self._cache) > 1
+                and self.stats["bytes_cached"] > self.max_bytes):
+            key = next(iter(self._cache))
+            evicted.append((key, self._cache[key].nbytes))
+            self.evict(key)
+        return evicted
 
-    def _enforce_budgets(self, owner: Optional[str]) -> None:
-        """Evict LRU-first until every configured budget holds.
-
-        Order: global entry count, global bytes, then the inserting
-        tenant's quota/bytes. Byte budgets always keep the newest entry —
-        a single multiply larger than the budget still serves (and is
-        evicted by whatever lands next), it just can't pin neighbours.
-        """
-        while len(self._cache) > self.maxsize:
-            self._evict(next(iter(self._cache)))
-        if self.max_bytes is not None:
-            while self.stats["bytes_cached"] > self.max_bytes \
-                    and len(self._cache) > 1:
-                self._evict(next(iter(self._cache)))
-        if owner is None or (self.tenant_quota is None
-                             and self.tenant_max_bytes is None):
-            return
-        owned = [k for k, e in self._cache.items() if e.owner == owner]
-        if self.tenant_quota is not None:
-            while len(owned) > self.tenant_quota:
-                self._evict(owned.pop(0))
-        if self.tenant_max_bytes is not None:
-            obytes = sum(self._cache[k].nbytes for k in owned)
-            while len(owned) > 1 and obytes > self.tenant_max_bytes:
-                k = owned.pop(0)
-                obytes -= self._cache[k].nbytes
-                self._evict(k)
-
-    def _plan(self, a: CSC, b: CSC, algorithm: str, nparts: int, grid: int,
-              layers: int, bs: int, nblocks: Optional[int],
-              semiring: Semiring, dtype, chunk: Optional[int]):
-        """Host planning only (the ``plan`` stage); returns
-        (plan, decode, repack, structure)."""
+    def _plan(self, call: Call, algorithm: str, geom: tuple):
+        """Host planning only (the ``plan`` stage) at the rung's geometry;
+        returns (plan, decode, repack, structure)."""
         from .spgemm_1d_device import (build_device_plan, decode_ring_output,
                                        repack_ring_payloads, ring_decode_map)
         from .spgemm_2d_device import (build_summa_plan, decode_summa_output,
@@ -416,14 +417,14 @@ class SpGEMMSession:
         with span("spgemm.session.plan"):
             if algorithm == "1d":
                 plan = build_device_plan(
-                    a, b, nparts, bs=bs, nblocks=nblocks, dtype=dtype,
-                    semiring=semiring,
-                    a_blockize_cache=self._blockize_cache, chunk=chunk)
+                    call.a, call.b, geom[0], bs=call.bs, nblocks=call.nblocks,
+                    dtype=call.dtype, semiring=call.semiring,
+                    a_blockize_cache=self._blockize_cache, chunk=call.chunk)
                 return (plan, decode_ring_output, repack_ring_payloads,
                         ring_decode_map)
             plan = build_summa_plan(
-                a, b, grid=grid, layers=layers if algorithm == "3d" else 1,
-                bs=bs, dtype=dtype, semiring=semiring)
+                call.a, call.b, grid=geom[0], layers=geom[1], bs=call.bs,
+                dtype=call.dtype, semiring=call.semiring)
             return plan, decode_summa_output, repack_summa_payloads, None
 
     def _compile(self, plan, algorithm: str, engine: str):
@@ -451,27 +452,15 @@ class SpGEMMSession:
                                             "engine": engine}) from e
         return compiled, list(args), scatter
 
-    # ---- the one public multiply ------------------------------------------
+    # ---- the multiply: prepare, then run ----------------------------------
 
-    def matmul(self, a: CSC, b: CSC, *,
-               algorithm: str = "1d",
-               nparts: int = 1,
-               grid: int = 1,
-               layers: int = 1,
-               bs: int = 32,
-               nblocks: Optional[int] = None,
-               semiring: Semiring = PLUS_TIMES,
-               engine: str = "auto",
-               dtype=np.float32,
-               chunk: Optional[int] = None,
-               tenant: Optional[str] = None) -> CSC:
-        """C = A ⊗ B on the device path, cached by structure.
-
-        ``tenant`` tags the cache entry a cold call creates with its
-        owner for the per-tenant budget/eviction accounting (serving
-        layer); it is deliberately NOT part of the cache key, so
-        structure-identical requests from different tenants share one
-        plan, one executable and one trace.
+    def prepare(self, a: CSC, b: CSC, *, algorithm: str = "1d",
+                nparts: int = 1, grid: int = 1, layers: int = 1,
+                bs: int = 32, nblocks: Optional[int] = None,
+                semiring: Semiring = PLUS_TIMES, engine: str = "auto",
+                dtype=np.float32, chunk: Optional[int] = None) -> Call:
+        """Check the knobs, validate the operands and fingerprint them:
+        the :class:`Call` that :meth:`run` serves.
 
         ``algorithm`` selects the distributed engine: ``"1d"`` (the
         sparsity-aware ring, geometry ``nparts``), ``"2d"`` (sparse SUMMA,
@@ -491,8 +480,6 @@ class SpGEMMSession:
         if chunk is not None and (not isinstance(chunk, int) or chunk < 1):
             raise ValueError(
                 f"chunk must be a positive int or None, got {chunk!r}")
-        engine = resolve_engine(engine)
-        self.stats["calls"] += 1
         if self.validate:
             try:
                 with span("spgemm.session.validate",
@@ -501,23 +488,34 @@ class SpGEMMSession:
             except ValidationError:
                 self.stats["validation_failures"] += 1
                 raise
+        with span("spgemm.session.fingerprint", hashed_bytes=sum(
+                16 + m.indptr.nbytes + m.indices.nbytes + m.data.nbytes
+                for m in (a, b))):
+            struct_fp = (structure_fingerprint(a), structure_fingerprint(b))
+            val_fp = (values_fingerprint(a), values_fingerprint(b))
+        return Call(a, b, algorithm, nparts, grid, layers, bs, nblocks,
+                    chunk, semiring, resolve_engine(engine), np.dtype(dtype),
+                    struct_fp, val_fp)
 
+    def run(self, call: Call) -> CSC:
+        """C = A ⊗ B on the device path for a prepared call, cached by
+        structure, down the degradation ladder."""
+        self.stats["calls"] += 1
         # the degradation ladder: engine fallback inside each algorithm
         # rung, then algorithm downgrade. Every rung is bitwise
         # oracle-equivalent, so descending trades comm volume for service.
         rungs = []
-        for alg in DOWNGRADE[algorithm]:
-            rungs.append((alg, engine))
-            if engine == "pallas":
+        for alg in DOWNGRADE[call.algorithm]:
+            rungs.append((alg, call.engine))
+            if call.engine == "pallas":
                 rungs.append((alg, "jnp"))
 
         retries_before = self.stats["retries"]
         last_err: Optional[SpGEMMError] = None
         for i, (alg_r, eng_r) in enumerate(rungs):
+            key = _key(call, alg_r, eng_r)
             try:
-                c, info = self._run_rung(a, b, alg_r, eng_r, algorithm,
-                                         nparts, grid, layers, bs, nblocks,
-                                         semiring, dtype, chunk, tenant)
+                c, info = self._run_rung(call, key, alg_r, eng_r)
             except (ValidationError, CompileError):
                 # an ingress rejection (e.g. a dtype-mismatched values-only
                 # repack) is deterministic: every rung would refuse it the
@@ -535,8 +533,9 @@ class SpGEMMSession:
             s = info["plan_stats"]
             self.last_call = dict(
                 cache_hit=info["cache_hit"], repacked=info["repacked"],
+                key=key, evicted=info["evicted"],
                 algorithm=alg_r, engine=eng_r,
-                requested_algorithm=algorithm, degraded=i > 0,
+                requested_algorithm=call.algorithm, degraded=i > 0,
                 retries=self.stats["retries"] - retries_before,
                 plan_seconds=info["plan_seconds"],
                 comm_bytes_planned=s["comm_bytes_planned"],
@@ -546,36 +545,19 @@ class SpGEMMSession:
             return c
         raise last_err
 
-    def _run_rung(self, a: CSC, b: CSC, algorithm: str, engine: str,
-                  requested: str, nparts: int, grid: int, layers: int,
-                  bs: int, nblocks: Optional[int], semiring: Semiring,
-                  dtype, chunk: Optional[int] = None,
-                  tenant: Optional[str] = None) -> Tuple[CSC, dict]:
-        """One rung of the ladder: serve the multiply with a fixed
-        (algorithm, engine), all four stages under retry + typed wrapping.
+    def matmul(self, a: CSC, b: CSC, **knobs) -> CSC:
+        """C = A ⊗ B on the device path, cached by structure: ``run`` of
+        ``prepare`` (which documents the knobs)."""
+        return self.run(self.prepare(a, b, **knobs))
 
-        A downgraded 1d rung inherits the 2d/3d call's device budget
-        (``grid*grid`` ring parts); a downgraded 2d rung keeps the grid and
-        collapses the layers.
-        """
-        if algorithm == "1d":
-            geom = (nparts if requested == "1d" else grid * grid,)
-        else:
-            geom = (grid, layers if algorithm == "3d" else 1)
-        with span("spgemm.session.fingerprint",
-                  hashed_bytes=fingerprint_nbytes(a, b)):
-            struct_fp = (structure_fingerprint(a), structure_fingerprint(b))
-            val_fp = (values_fingerprint(a), values_fingerprint(b))
-        # nblocks and chunk are 1D-ring knobs (Algorithm-2 fetch grouping /
-        # the double-buffered chunk size); the SUMMA planners have neither,
-        # so they must not split byte-identical 2d/3d plans into distinct
-        # entries
-        key = (algorithm, geom, bs,
-               nblocks if algorithm == "1d" else None,
-               chunk if algorithm == "1d" else None,
-               semiring.name, engine, np.dtype(dtype).str, *struct_fp)
+    def _run_rung(self, call: Call, key: tuple, algorithm: str,
+                  engine: str) -> Tuple[CSC, dict]:
+        """One rung of the ladder: serve the call from the entry ``key``
+        with a fixed (algorithm, engine), all four stages under retry +
+        typed wrapping."""
+        a, b, val_fp, dtype = call.a, call.b, call.val_fp, call.dtype
         ctx = {"algorithm": algorithm, "engine": engine,
-               "requested_algorithm": requested}
+               "requested_algorithm": call.algorithm}
         failures = self._quarantine.get(key, 0)
         if failures >= self.breaker_threshold:
             raise DeviceExecError(
@@ -589,28 +571,27 @@ class SpGEMMSession:
         plan_seconds = 0.0
         try:
             if hit:
-                if val_fp != entry.val_fp:
-                    # values-only repacks blockize straight into the plan's
-                    # payload stacks; a dtype-mismatched operand would be
-                    # cast silently (float64 values narrowed into a
-                    # float32-keyed entry) and still count as a cache hit —
-                    # reject at ingress instead, before anything mutates
-                    mism = [
-                        f"operand {nm} has data dtype "
+                # the operands whose values changed since the entry ran
+                sides = tuple(m if val_fp[i] != entry.val_fp[i] else None
+                              for i, m in enumerate((a, b)))
+                # values-only repacks blockize straight into the plan's
+                # payload stacks; a dtype-mismatched operand would be cast
+                # silently (float64 values narrowed into a float32-keyed
+                # entry) and still count as a cache hit — reject at
+                # ingress instead, before anything mutates
+                mism = [f"operand {nm} has data dtype "
                         f"{np.dtype(m.data.dtype).name}"
-                        for nm, i, m in (("a", 0, a), ("b", 1, b))
-                        if val_fp[i] != entry.val_fp[i]
-                        and np.dtype(m.data.dtype) != np.dtype(dtype)]
-                    if mism:
-                        self.stats["validation_failures"] += 1
-                        raise ValidationError(
-                            "dtype-mismatched values-only repack: "
-                            + "; ".join(mism)
-                            + f" but the cached plan's payloads are "
-                            f"{np.dtype(dtype).name} — repacking would "
-                            "silently narrow the values; cast the operand "
-                            "or request a matching dtype=",
-                            stage="repack", context=ctx)
+                        for nm, m in zip("ab", sides)
+                        if m is not None and np.dtype(m.data.dtype) != dtype]
+                if mism:
+                    self.stats["validation_failures"] += 1
+                    raise ValidationError(
+                        "dtype-mismatched values-only repack: "
+                        + "; ".join(mism)
+                        + f" but the cached plan's payloads are "
+                        f"{dtype.name} — repacking would silently narrow "
+                        "the values; cast the operand or request a "
+                        "matching dtype=", stage="repack", context=ctx)
                 self._cache.move_to_end(key)
                 self.stats["plan_cache_hits"] += 1
                 self.stats["plan_seconds_saved"] += \
@@ -630,9 +611,6 @@ class SpGEMMSession:
                     # so a half-swapped payload stack can never serve a
                     # call.
                     def do_repack():
-                        sides = tuple(
-                            m if val_fp[i] != entry.val_fp[i] else None
-                            for i, m in enumerate((a, b)))
                         scatter = entry.scatter
                         with span("spgemm.repack.blockize",
                                   tiles=_tile_slots(entry.plan, *sides)):
@@ -664,21 +642,17 @@ class SpGEMMSession:
                 t0 = time.perf_counter()
                 plan, decode, repack, structure = self._stage(
                     "plan",
-                    lambda: self._plan(a, b, algorithm, geom[0], grid,
-                                       layers, bs, nblocks, semiring,
-                                       dtype, chunk),
-                    ctx)
+                    lambda: self._plan(call, algorithm, key[1]), ctx)
                 fn, args, scatter = self._stage(
                     "compile",
                     lambda: self._compile(plan, algorithm, engine), ctx)
                 plan_seconds = time.perf_counter() - t0
                 entry = _Entry(plan, fn, args, decode, repack, val_fp,
-                               owner=tenant, scatter=scatter,
-                               structure=structure)
+                               scatter=scatter, structure=structure)
 
             def do_execute():
                 with span("spgemm.execute.dispatch",
-                          mxu_passes=semiring.mxu_passes):
+                          mxu_passes=call.semiring.mxu_passes):
                     res = entry.fn(*entry.args)
                 if hit and entry.structure is not None \
                         and entry.plan.decode_map is None:
@@ -691,26 +665,26 @@ class SpGEMMSession:
                 return entry.decode(entry.plan, out)
 
             c = self._stage("execute", do_execute, ctx)
-        except (ValidationError, CompileError):
-            # ingress rejection of a malformed request: the cached entry is
-            # healthy and untouched — quarantining it (or bumping its
+        except SpGEMMError as e:
+            # an ingress rejection of a malformed request leaves the cached
+            # entry healthy and untouched — quarantining it (or bumping its
             # breaker) would punish the cache for the caller's operand. A
             # compile failure left nothing cached, and an open breaker
             # would turn it into a laddered DeviceExecError on the next call
-            raise
-        except SpGEMMError:
-            self._record_failure(key)
+            if not isinstance(e, (ValidationError, CompileError)):
+                self._record_failure(key)
             raise
         # success: only now may a cold entry enter the cache — a plan that
         # never executed cleanly is never cached, so injected faults can't
         # poison it — and the key's breaker resets
+        evicted = []
         if not hit:
             self.stats["plan_cache_misses"] += 1
             self._cache[key] = entry
             self.stats["bytes_cached"] += entry.nbytes
-            self._enforce_budgets(tenant)
+            evicted = self._enforce_budgets()
         self._quarantine.pop(key, None)
-        return c, dict(cache_hit=hit, repacked=repacked,
+        return c, dict(cache_hit=hit, repacked=repacked, evicted=evicted,
                        plan_seconds=plan_seconds,
                        plan_stats=entry.plan.stats)
 
@@ -729,16 +703,23 @@ class SpGEMMSession:
         self._quarantine.clear()
         self.stats["bytes_cached"] = 0
 
-    def cached_bytes(self, tenant: Optional[str] = None) -> int:
-        """Device bytes pinned by cached entries — all of them, or only
-        those created by ``tenant``."""
-        if tenant is None:
-            return int(self.stats["bytes_cached"])
-        return sum(e.nbytes for e in self._cache.values()
-                   if e.owner == tenant)
+    def cached_bytes(self) -> int:
+        """Device bytes pinned by cached entries."""
+        return int(self.stats["bytes_cached"])
 
-    def cached_entries(self, tenant: Optional[str] = None) -> int:
-        """Cached entry count — all, or only those created by ``tenant``."""
-        if tenant is None:
-            return len(self._cache)
-        return sum(1 for e in self._cache.values() if e.owner == tenant)
+    def cached_entries(self) -> int:
+        """Cached entry count."""
+        return len(self._cache)
+
+    def entries(self) -> List[Tuple[tuple, int]]:
+        """(key, nbytes) of every cached entry, least recently used
+        first."""
+        return [(k, e.nbytes) for k, e in self._cache.items()]
+
+    def evict(self, key: tuple) -> None:
+        """Evict one cached entry: release its device buffers and settle
+        the byte ledger (counted in ``stats["evictions"]``)."""
+        entry = self._cache.pop(key)
+        self.stats["evictions"] += 1
+        self.stats["bytes_cached"] -= entry.nbytes
+        entry.release()

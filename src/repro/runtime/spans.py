@@ -1,7 +1,7 @@
 """Named profiler spans on the served multiply's host path.
 
-Every phase of a served multiply that the host does — coalescing in the
-service, validation and fingerprints at session ingress, the values-only
+Every phase of a served multiply that the host does — validation and
+fingerprints at session ingress, coalescing in the service, the values-only
 repack, the dispatch and fetch of the compiled program, the decode — runs
 inside a :func:`span`: a ``jax.profiler.TraceAnnotation`` whose keyword
 counters become stats of the event. The spans are ``TraceMe`` events, so
@@ -31,15 +31,15 @@ __all__ = ["SPANS", "span"]
 # every span the program records, with what it covers and its counters
 # (tests/test_spans.py pins the names; the benchmark's per-layer metrics in
 # bench/metrics/ read them):
+#   spgemm.session.validate   : SpGEMMSession.prepare's validation of both
+#                               operands; nnz (entries checked) -> ingress_ms
+#   spgemm.session.fingerprint: SpGEMMSession.prepare's structure and values
+#                               fingerprints of both operands (four blake2b,
+#                               the only hashing pass of a served request);
+#                               hashed_bytes -> ingress_ms
 #   spgemm.service.coalesce   : SpGEMMService.run_pending's grouping of the
-#                               queued requests by execution key (four
-#                               blake2b fingerprints per request);
-#                               requests, hashed_bytes -> ingress_ms
-#   spgemm.session.validate   : ingress validation of both operands;
-#                               nnz (entries checked) -> ingress_ms
-#   spgemm.session.fingerprint: the structure fingerprints of the cache key
-#                               and the values fingerprints; hashed_bytes
-#                               -> ingress_ms
+#                               prepared requests on their calls' group
+#                               keys (no hashing); requests -> ingress_ms
 #   spgemm.session.plan       : host planning of a cold key
 #   spgemm.session.compile    : placing the plan and compiling its program
 #                               (cold key); both set-up, read by no metric

@@ -17,17 +17,17 @@ anything itself):
     a ticket; :meth:`run_pending` drains the queue and returns a
     ``{ticket: ServedResult}`` map (:meth:`serve` is the submit+drain
     convenience for a whole batch).
-  * **fingerprint coalescing** — queued requests are grouped by execution
-    key (algorithm, geometry, semiring, dtype, *structure and values
-    fingerprints*): N concurrent callers multiplying the same shared
-    graph cost ONE session multiply — one plan, one executable, one
-    trace — and all N receive the same decoded result. Same structure
-    with different values is a separate group that rides the session's
-    values-only repack path on the shared cached plan.
-  * **per-tenant budgets** — cold entries a tenant creates are tagged
-    with its name; the session's ``tenant_quota`` / ``tenant_max_bytes``
-    / ``max_bytes`` LRU budgets bound device memory, and the service
-    attributes every eviction per tenant (``evictions_by_tenant``).
+  * **fingerprint coalescing** — the session prepares (validates and
+    fingerprints) each queued request once, and requests are grouped on
+    the prepared ``Call.group`` (cache key + values fingerprints): N
+    concurrent callers multiplying the same shared graph cost ONE session
+    multiply — one plan, one executable, one trace — and all N receive the
+    same decoded result. Same structure with different values is a
+    separate group that rides the values-only repack on the shared plan.
+  * **per-tenant budgets** — the service owns tenant accounting: it
+    records which tenant created each cached entry, enforces
+    ``tenant_quota`` / ``tenant_max_bytes`` over them LRU-first, and
+    charges every eviction to the creator (``evictions_by_tenant``).
   * **warm-plan prefetch** — :meth:`prefetch` pre-builds (and caches) the
     plan/executable for a declared structure, so a tenant's first real
     request is already a cache hit.
@@ -54,14 +54,13 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from collections import Counter, OrderedDict
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from ..core.semiring import PLUS_TIMES, Semiring
-from ..core.session import (SpGEMMSession, fingerprint_nbytes,
-                            structure_fingerprint, values_fingerprint)
+from ..core.session import Call, SpGEMMSession
 from ..core.sparse import CSC
 from ..core.validate import SpGEMMError
 from ..runtime.fault_tolerance import CircuitBreaker
@@ -74,8 +73,8 @@ __all__ = ["SERVICE_STATS", "ServicePolicy", "SpGEMMRequest", "ServedResult",
 # keys; benchmarks/serving_throughput.py exports them as rows:
 #   requests            : tickets admitted (incl. later rejections)
 #   served              : requests answered with a result
-#   failed              : requests whose group's multiply failed (typed
-#                         SpGEMMError after the session's ladder)
+#   failed              : requests refused at the session's ingress or whose
+#                         group's multiply failed (typed SpGEMMError)
 #   rejected_breaker    : requests refused at admission — tenant's circuit
 #                         was open
 #   coalesced           : requests served by another request's multiply
@@ -90,8 +89,8 @@ __all__ = ["SERVICE_STATS", "ServicePolicy", "SpGEMMRequest", "ServedResult",
 #   bytes_moved_planned / bytes_moved_padded : communication bytes of the
 #                         executed plans, summed per executed group
 #   prefetched          : warm-plan prefetches performed
-#   evictions_by_tenant : {tenant: evictions} attributed via the session's
-#                         on_evict hook (entry creator pays)
+#   evictions_by_tenant : {tenant: evictions} that the service's calls
+#                         caused (entry creator pays)
 SERVICE_STATS = ("requests", "served", "failed", "rejected_breaker",
                  "coalesced", "coalesce_rate", "cache_hits",
                  "cache_hit_rate", "latency_p50_s", "latency_p99_s",
@@ -103,9 +102,10 @@ SERVICE_STATS = ("requests", "served", "failed", "rejected_breaker",
 class ServicePolicy:
     """Admission/budget policy, fixed at service construction.
 
-    ``tenant_quota`` / ``tenant_max_bytes`` / ``max_bytes`` forward to the
+    ``tenant_quota`` / ``tenant_max_bytes`` bound the entries each tenant
+    created; the service enforces them. ``max_bytes`` forwards to the
     session the service creates (ignored when a session is supplied — its
-    own budgets stand). ``coalesce=False`` disables fingerprint grouping
+    own budget stands). ``coalesce=False`` disables fingerprint grouping
     (every request is its own session call; the serving benchmark's
     baseline). Breaker knobs shape the per-tenant circuit breakers.
     """
@@ -120,13 +120,8 @@ class ServicePolicy:
 
 @dataclasses.dataclass
 class SpGEMMRequest:
-    """One admission-queue entry: tenant + operands + execution knobs.
-
-    The knobs mirror :meth:`SpGEMMSession.matmul`; ``nblocks``/``chunk``
-    are 1D-only and normalized away for 2d/3d in the execution key,
-    exactly as the session's cache key does — requests that the session
-    would serve from one entry must coalesce into one group.
-    """
+    """One admission-queue entry: tenant + operands + the execution knobs
+    of :meth:`SpGEMMSession.prepare`."""
 
     tenant: str
     a: CSC
@@ -141,21 +136,6 @@ class SpGEMMRequest:
     chunk: Optional[int] = None
     dtype: type = np.float32
     engine: str = "auto"
-
-    def exec_key(self) -> tuple:
-        """Full coalescing key: two requests with equal keys are satisfied
-        by one multiply (structure AND values fingerprints — values-only
-        variants are distinct groups riding the repack path)."""
-        is_1d = self.algorithm == "1d"
-        return (self.algorithm,
-                self.nparts if is_1d else None,
-                self.grid, self.layers, self.bs,
-                self.nblocks if is_1d else None,
-                self.chunk if is_1d else None,
-                self.semiring.name, self.engine,
-                np.dtype(self.dtype).str,
-                structure_fingerprint(self.a), structure_fingerprint(self.b),
-                values_fingerprint(self.a), values_fingerprint(self.b))
 
     def matmul_kwargs(self) -> dict:
         return dict(algorithm=self.algorithm, semiring=self.semiring,
@@ -198,8 +178,8 @@ class TenantOverloadError(SpGEMMError):
 class SpGEMMService:
     """Request-driven multi-tenant SpGEMM service over one shared session.
 
-    ``session`` — bring your own (its budgets stand), or None to have the
-    service build one from ``policy`` (``interpret`` and any extra
+    ``session`` — bring your own (its ``max_bytes`` stands), or None to
+    have the service build one from ``policy`` (``interpret`` and any extra
     ``session_kwargs`` — fault injectors, retry policy, injectable retry
     sleep — forward to the constructor).
     ``clock`` — injectable monotonic-seconds source for latency
@@ -212,32 +192,24 @@ class SpGEMMService:
                  clock: Callable[[], float] = time.monotonic,
                  interpret: Optional[bool] = None,
                  **session_kwargs):
+        if any(v is not None and v < 1
+               for v in (policy.tenant_quota, policy.tenant_max_bytes)):
+            raise ValueError("tenant budgets must be >= 1 or None")
         self.policy = policy
         self.clock = clock
         if session is None:
-            session = SpGEMMSession(
-                interpret=interpret,
-                max_bytes=policy.max_bytes,
-                tenant_quota=policy.tenant_quota,
-                tenant_max_bytes=policy.tenant_max_bytes,
-                **session_kwargs)
+            session = SpGEMMSession(interpret=interpret,
+                                    max_bytes=policy.max_bytes,
+                                    **session_kwargs)
         elif interpret is not None or session_kwargs:
             raise ValueError(
                 "interpret/session kwargs are fixed when the session is "
                 "created; construct the SpGEMMSession yourself or let the "
                 "service build it")
         self.session = session
-        self._evictions_by_tenant: Dict[str, int] = {}
-        prev_hook = session.on_evict
-
-        def _on_evict(owner, key, nbytes, _prev=prev_hook):
-            name = owner if owner is not None else "<untagged>"
-            self._evictions_by_tenant[name] = \
-                self._evictions_by_tenant.get(name, 0) + 1
-            if _prev is not None:
-                _prev(owner, key, nbytes)
-
-        session.on_evict = _on_evict
+        # cache key -> the tenant whose call created the entry
+        self._owners: Dict[tuple, str] = {}
+        self._evictions_by_tenant: Dict[str, int] = Counter()
         self._breakers: Dict[str, CircuitBreaker] = {}
         self._queue: List[Tuple[int, SpGEMMRequest]] = []
         self._rejected: Dict[int, ServedResult] = {}
@@ -294,60 +266,97 @@ class SpGEMMService:
         is a full multiply whose result is discarded). Returns True if the
         plan is now warm; a failed prefetch counts against the tenant's
         breaker exactly like a failed request."""
-        req = SpGEMMRequest(tenant=tenant, a=a, b=b, **knobs)
         self._counts["prefetched"] += 1
         try:
-            self.session.matmul(req.a, req.b, tenant=tenant,
-                                **req.matmul_kwargs())
+            self._run(tenant, self.session.prepare(a, b, **knobs))
         except SpGEMMError:
             self._breaker(tenant).record_failure()
             return False
         self._breaker(tenant).record_success()
         return True
 
+    # ---- tenant accounting -------------------------------------------------
+
+    def _run(self, tenant: str, call: Call) -> CSC:
+        """One session multiply on ``tenant``'s behalf, then the tenant
+        accounting: a cold call's entry is the tenant's, its budgets are
+        enforced LRU-first over its entries, and every eviction — the
+        session's global ones included — is charged to the creator."""
+        c = self.session.run(call)
+        last = self.session.last_call
+        if not last["cache_hit"]:
+            self._owners[last["key"]] = tenant
+        evicted = [k for k, _ in last["evicted"]]
+        cached = self.session.entries()
+        owned = [(k, n) for k, n in cached if self._owners.get(k) == tenant]
+        quota, cap = self.policy.tenant_quota, self.policy.tenant_max_bytes
+        while (quota is not None and len(owned) > quota) or (
+                cap is not None and len(owned) > 1
+                and sum(n for _, n in owned) > cap):
+            evicted.append(owned.pop(0)[0])
+            self.session.evict(evicted[-1])
+        for key in evicted:
+            self._evictions_by_tenant[self._owners.pop(key, "<untagged>")] \
+                += 1
+        # forget entries the session quarantined or dropped
+        live = {k for k, _ in cached}
+        self._owners = {k: t for k, t in self._owners.items() if k in live}
+        return c
+
+    def cached_entries(self, tenant: Optional[str] = None) -> int:
+        """Cached entry count — all, or those ``tenant`` made."""
+        return sum(1 for k, _ in self.session.entries()
+                   if tenant is None or self._owners.get(k) == tenant)
+
+    def cached_bytes(self, tenant: Optional[str] = None) -> int:
+        """Device bytes of cached entries — all, or those ``tenant`` made."""
+        return sum(n for k, n in self.session.entries()
+                   if tenant is None or self._owners.get(k) == tenant)
+
     # ---- the drain loop ----------------------------------------------------
 
     def run_pending(self) -> Dict[int, ServedResult]:
-        """Drain the admission queue: coalesce, execute one multiply per
-        group through the session, deliver every outstanding outcome
-        (including admission rejections) keyed by ticket."""
+        """Drain the admission queue: prepare each request once, coalesce,
+        execute one multiply per group through the session, deliver every
+        outstanding outcome (including admission rejections) keyed by
+        ticket."""
         batch, self._queue = self._queue, []
         out, self._rejected = self._rejected, {}
 
-        groups: "OrderedDict[tuple, list]" = OrderedDict()
-        hashed = sum(fingerprint_nbytes(r.a, r.b) for _, r in batch) \
-            if self.policy.coalesce else 0
-        with span("spgemm.service.coalesce", requests=len(batch),
-                  hashed_bytes=hashed):
-            for ticket, req in batch:
-                # coalescing off → every ticket is its own group
-                key = req.exec_key() if self.policy.coalesce \
-                    else ("!", ticket)
-                groups.setdefault(key, []).append((ticket, req))
-
-        for members in groups.values():
-            t0 = self.clock()
-            _, leader = members[0]
-            err: Optional[SpGEMMError] = None
-            c = None
+        calls: List[Union[Call, SpGEMMError]] = []
+        for _, req in batch:
             try:
-                c = self.session.matmul(leader.a, leader.b,
-                                        tenant=leader.tenant,
-                                        **leader.matmul_kwargs())
+                calls.append(self.session.prepare(req.a, req.b,
+                                                  **req.matmul_kwargs()))
             except SpGEMMError as e:
-                err = e
+                calls.append(e)
+        groups: "OrderedDict[object, tuple]" = OrderedDict()
+        with span("spgemm.service.coalesce", requests=len(batch)):
+            for (ticket, req), call in zip(batch, calls):
+                # coalescing off, or refused at the session's ingress →
+                # the ticket is its own group
+                key = call.group if self.policy.coalesce \
+                    and isinstance(call, Call) else ticket
+                groups.setdefault(key, (call, []))[1].append((ticket, req))
+
+        for call, members in groups.values():
+            t0 = self.clock()
+            c, err = None, call if isinstance(call, SpGEMMError) else None
+            if err is None:
+                try:
+                    c = self._run(members[0][1].tenant, call)
+                except SpGEMMError as e:
+                    err = e
             latency = self.clock() - t0
             ok = err is None
-            call = dict(self.session.last_call) if ok else {}
+            stats = dict(self.session.last_call) if ok else {}
             if ok:
                 self._counts["groups_executed"] += 1
                 self._counts["served"] += len(members)
                 self._counts["coalesced"] += len(members) - 1
-                if call.get("cache_hit"):
-                    self._counts["cache_hits"] += 1
-                self._bytes["planned"] += int(
-                    call.get("comm_bytes_planned", 0))
-                self._bytes["padded"] += int(call.get("comm_bytes_padded", 0))
+                self._counts["cache_hits"] += stats["cache_hit"]
+                self._bytes["planned"] += int(stats["comm_bytes_planned"])
+                self._bytes["padded"] += int(stats["comm_bytes_padded"])
             else:
                 self._counts["failed"] += len(members)
             for i, (ticket, req) in enumerate(members):
@@ -360,8 +369,8 @@ class SpGEMMService:
                 out[ticket] = ServedResult(
                     tenant=req.tenant, ok=ok, value=c, error=err,
                     coalesced=len(members) > 1, leader=i == 0,
-                    cache_hit=bool(call.get("cache_hit", False)),
-                    latency_s=latency, call_stats=call)
+                    cache_hit=bool(stats.get("cache_hit", False)),
+                    latency_s=latency, call_stats=stats)
         return out
 
     def serve(self, requests: Sequence[SpGEMMRequest]) -> List[ServedResult]:

@@ -9,12 +9,14 @@ discipline extended to serving).
 import numpy as np
 import pytest
 
+from repro.core import session as session_mod
 from repro.core.session import SpGEMMSession
 from repro.core.sparse import banded_clustered, erdos_renyi
 from repro.core.spgemm_1d import spgemm_1d
 from repro.core.validate import ValidationError
 from repro.serve import (SERVICE_STATS, ServicePolicy, SpGEMMRequest,
                          SpGEMMService, TenantOverloadError)
+from repro.serve import spgemm_service as service_mod
 
 
 class Clock:
@@ -114,19 +116,22 @@ def test_values_variant_rides_repack_path(shared_graph):
     np.testing.assert_array_equal(second.value.data, want.data)
 
 
-def test_tenant_quota_evicts_only_that_tenant():
+@pytest.mark.parametrize("own_session", [False, True])
+def test_tenant_quota_evicts_only_that_tenant(own_session):
     """tenant_quota bounds entries per tenant, LRU-first, and the
     eviction is attributed to the owning tenant — another tenant's
-    cached plans are untouched."""
-    svc = SpGEMMService(policy=ServicePolicy(tenant_quota=2))
+    cached plans are untouched. The service enforces it on a session it
+    was handed as on one it built."""
+    svc = SpGEMMService(SpGEMMSession() if own_session else None,
+                        policy=ServicePolicy(tenant_quota=2))
     gb = _distinct(9)
     assert svc.serve([SpGEMMRequest(tenant="b", a=gb, b=gb, bs=16)])[0].ok
     for i in range(3):
         g = _distinct(i)
         assert svc.serve([SpGEMMRequest(tenant="a", a=g, b=g, bs=16)])[0].ok
 
-    assert svc.session.cached_entries("a") == 2
-    assert svc.session.cached_entries("b") == 1
+    assert svc.cached_entries("a") == 2
+    assert svc.cached_entries("b") == 1
     assert svc.stats()["evictions_by_tenant"] == {"a": 1}
     # the evicted (oldest) structure replans on return; the survivor hits
     g0 = _distinct(0)
@@ -254,7 +259,7 @@ def test_coalesce_disabled_serves_per_request(shared_graph):
 
 
 def test_byo_session_rejects_stale_kwargs(shared_graph):
-    sess = SpGEMMSession(tenant_quota=4)
+    sess = SpGEMMSession(maxsize=4)
     svc = SpGEMMService(session=sess)
     assert svc.session is sess
     with pytest.raises(ValueError):
@@ -267,3 +272,86 @@ def test_serve_empty_batch():
     svc = SpGEMMService()
     assert svc.serve([]) == []
     assert svc.run_pending() == {}
+
+
+@pytest.mark.parametrize("values_only", [False, True],
+                         ids=["cold", "values_only"])
+def test_one_served_request_fingerprints_each_operand_once(
+        monkeypatch, values_only):
+    """A served request's structure and values fingerprints are computed
+    once each per operand, on a cold call and on a values-only call:
+    every module that binds a fingerprint function is counted."""
+    a, b = _graph(seed=1), _graph(seed=1)
+    svc = SpGEMMService()
+    if values_only:
+        assert svc.serve([SpGEMMRequest(tenant="t", a=a, b=b, bs=16)])[0].ok
+        a = a.astype(np.float32)
+        a.data[:] += 1.0
+    counts = {}
+
+    def counting(name, fn):
+        def wrapped(m):
+            counts[name, id(m)] = counts.get((name, id(m)), 0) + 1
+            return fn(m)
+        return wrapped
+
+    for mod in (session_mod, service_mod):
+        for name in ("structure_fingerprint", "values_fingerprint"):
+            if hasattr(mod, name):
+                monkeypatch.setattr(mod, name,
+                                    counting(name, getattr(mod, name)))
+    (r,) = svc.serve([SpGEMMRequest(tenant="t", a=a, b=b, bs=16)])
+    assert r.ok and r.cache_hit == values_only
+    assert r.call_stats["repacked"] == values_only
+    assert counts == {(name, id(m)): 1
+                      for name in ("structure_fingerprint",
+                                   "values_fingerprint")
+                      for m in (a, b)}
+
+
+def _respelled(g, algorithm, change):
+    """The request for ``g`` under ``algorithm`` and a second request
+    that differs from it only by ``change``."""
+    geom = dict(nparts=1) if algorithm == "1d" else dict(grid=1)
+    first = SpGEMMRequest(tenant="x", a=g, b=g, bs=16, algorithm=algorithm,
+                          **geom)
+    knobs = dict(tenant="y", a=g, b=g, bs=16, algorithm=algorithm, **geom)
+    if change == "values":
+        g2 = g.astype(np.float32)
+        g2.data[:] += 1.0
+        knobs.update(a=g2, b=g2)
+    elif change == "unused_geometry":
+        # the grid of a 1d call and the layers of a 2d call plan nothing
+        knobs["grid" if algorithm == "1d" else "layers"] = 2
+    elif change == "engine":
+        knobs.update(engine="pallas")        # what "auto" resolves to
+    elif change != "same":
+        knobs[change] = 2
+    return first, SpGEMMRequest(**knobs)
+
+
+@pytest.mark.parametrize("change", ["same", "nblocks", "chunk", "values",
+                                    "unused_geometry", "engine"])
+@pytest.mark.parametrize("algorithm", ["1d", "2d"])
+def test_coalesce_exactly_when_one_entry_serves_both(
+        shared_graph, algorithm, change):
+    """Two requests coalesce into one group exactly when the session,
+    serving them one after the other, answers the second from the
+    first's cache entry without a repack."""
+    first, second = _respelled(shared_graph, algorithm, change)
+    sess = SpGEMMSession()
+    solo = SpGEMMService(sess, policy=ServicePolicy(coalesce=False)) \
+        .serve([first, second])
+    assert all(r.ok for r in solo)
+    one_entry = solo[1].call_stats["key"] == solo[0].call_stats["key"]
+    assert one_entry == solo[1].cache_hit
+    same_answer = one_entry and not solo[1].call_stats["repacked"]
+
+    both = SpGEMMService(sess).serve([first, second])
+    assert all(r.ok for r in both)
+    assert both[1].coalesced == same_answer
+    assert same_answer == (change in ("same", "unused_geometry", "engine")
+                           or (change in ("nblocks", "chunk")
+                               and algorithm == "2d"))
+    for r, req in zip(both, (first, second)):
+        np.testing.assert_array_equal(r.value.data, _oracle(req.a).data)
